@@ -89,7 +89,7 @@ def run():
              bench(lambda: boundary_bitmap(arr, DEFAULT_PARAMS), 500))
         emit(f"rollinghash_pallas_{tag}",
              bench(lambda: pallas_bitmap(arr), 100),
-             "interpret-mode on CPU; TPU path identical kernel")
+             "Pallas interpreter off the TPU: a CPU timing")
         store = ChunkStore()
         chunkraw = encode_chunk(3, payload)
         n = [0]

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the paper's Put hot spots: the content-defined
+chunking scan (chunker.py) and the cid hash (fphash.py), with their
+numpy oracles (ref.py) and the engine hooks (ops.py)."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret() -> bool:
+    """True off the TPU.  The one place the kernels' platform decision is
+    made, read per call (never snapshotted at import): on a TPU every
+    kernel runs compiled; elsewhere the Pallas kernels run in the
+    interpreter and ``fphash_many`` takes its vectorized numpy sponge —
+    the CPU path the tests use."""
+    return jax.default_backend() != "tpu"

@@ -19,8 +19,9 @@ pure function of the lane index).  The kernel processes SUBLANES=8 rows per
 grid step as a (8, ROW_LEN) u32 tile in VMEM — one boundary flag per
 payload byte.
 
-Validated against ref.boundary_bitmap_ref in interpret mode (this container
-is CPU-only); compiled path is exercised by tests/test_kernels.py.
+Validated bit for bit against ref.boundary_bitmap_ref: in interpret mode
+by tests/test_kernels.py, compiled on a TPU by chip_smoke.py.
+tests/test_tpu_compile.py compiles it for a v5e chip at 8 MiB.
 """
 from __future__ import annotations
 
@@ -30,6 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from .. import obs
+from . import interpret
 
 ROW_STRIDE = 4992          # payload bytes per row (multiple of 32 and 128)
 HALO = 128                 # front halo >= window (multiple of 32)
@@ -82,8 +86,10 @@ def _chunker_kernel(x_ref, out_ref, *, window: int, q: int, seed: int):
     out_ref[...] = hit[:, HALO:].astype(jnp.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "q", "seed", "nrows"))
-def _run(rows, *, window: int, q: int, seed: int, nrows: int):
+@functools.partial(jax.jit, static_argnames=("window", "q", "seed",
+                                             "interpret"))
+def _run(rows, *, window: int, q: int, seed: int, interpret: bool):
+    nrows = rows.shape[0]
     grid = nrows // SUBLANES
     return pl.pallas_call(
         functools.partial(_chunker_kernel, window=window, q=q, seed=seed),
@@ -91,13 +97,8 @@ def _run(rows, *, window: int, q: int, seed: int, nrows: int):
         in_specs=[pl.BlockSpec((SUBLANES, ROW_LEN), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((SUBLANES, ROW_STRIDE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nrows, ROW_STRIDE), jnp.uint8),
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(rows)
-
-
-# CPU container: interpret mode (executes the kernel body in Python);
-# on TPU this flips to False and the same BlockSpecs drive real VMEM tiles.
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def boundary_bitmap_pallas(data: np.ndarray, window: int, q: int,
@@ -113,11 +114,12 @@ def boundary_bitmap_pallas(data: np.ndarray, window: int, q: int,
     padded = np.zeros(nrows * ROW_STRIDE + HALO, dtype=np.uint8)
     padded[HALO:HALO + n] = data
     # overlapping rows: row r covers padded[r*STRIDE : r*STRIDE + ROW_LEN)
-    idx = (np.arange(nrows)[:, None] * ROW_STRIDE
-           + np.arange(ROW_LEN)[None, :])
-    rows = padded[idx]
+    rows = np.lib.stride_tricks.sliding_window_view(
+        padded, ROW_LEN)[::ROW_STRIDE]
     out = np.asarray(_run(rows, window=window, q=q, seed=seed,
-                          nrows=nrows))
+                          interpret=interpret()))
+    obs.inc("kernel_launches", labels={"kernel": "chunker"})
+    obs.inc("kernel_bytes", n, labels={"kernel": "chunker"})
     bitmap = out.reshape(-1)[:n].astype(bool)
     bitmap[:window - 1] = False               # no full window yet
     return bitmap

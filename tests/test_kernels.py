@@ -6,7 +6,8 @@ pytest.importorskip("hypothesis")  # property tests need the dev extra
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.chunker import boundary_bitmap_pallas
-from repro.kernels.fphash import fphash
+from repro.kernels.fphash import (fphash, fphash_many, fphash_many_host,
+                                  fphash_many_kernel)
 from repro.kernels.ops import use_pallas_chunker
 from repro.kernels.ref import boundary_bitmap_ref, fphash_ref
 
@@ -33,8 +34,20 @@ def test_chunker_property(data, w):
 
 @pytest.mark.parametrize("n", [0, 1, 31, 4095, 4096, 4097, 12288, 65536])
 def test_fphash_matches_ref(n, rng):
+    """The batched Pallas kernel body (interpret mode), the singular and
+    batched entry points and the numpy oracle agree bit for bit."""
     data = rng.bytes(n)
-    assert fphash(data) == fphash_ref(data)
+    want = fphash_ref(data)
+    assert fphash_many_kernel([data], interpret=True) == [want]
+    assert fphash(data) == fphash_many([data])[0] == want
+
+
+def test_fphash_kernel_batch_spans_output_tiles(rng):
+    """More than 128 chunks of mixed block counts in one call: digests
+    pack 128 chunks per output tile, several buckets launch."""
+    blobs = [rng.bytes(int(n)) for n in rng.integers(0, 3 * 4096, 300)]
+    assert fphash_many_kernel(blobs, interpret=True) == \
+        fphash_many_host(blobs)
 
 
 def test_fphash_avalanche(rng):

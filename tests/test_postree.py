@@ -10,6 +10,7 @@ from repro.core import chunk as ck
 from repro.core.chunker import ChunkParams
 from repro.core.chunkstore import ChunkStore
 from repro.core.postree import POSTree
+from repro.errors import TamperedChunk
 
 P8 = ChunkParams(q=8)
 
@@ -151,5 +152,5 @@ def test_tamper_evidence(rng):
     t = POSTree.build_bytes(s, data, P8)
     cid = t.levels[0][3].cid
     s._data[cid] = b"\x03tampered!"          # corrupt a stored chunk
-    with pytest.raises(AssertionError):
+    with pytest.raises(TamperedChunk):
         s.get(cid)

@@ -465,7 +465,7 @@ def test_replay_stats_match_fresh_reexecution(tmp_path, rng):
             st.tuples(st.just("reopen"), st.just(0))),
         min_size=1, max_size=40),
            seed=st.integers(0, 2**31 - 1))
-    def prop(ops, seed, tmp_path=tmp_path):
+    def prop(ops, seed):
         import numpy as np
         rng = np.random.default_rng(seed)
         pool = chunks(rng, n=12, size=200)
@@ -478,9 +478,9 @@ def test_replay_stats_match_fresh_reexecution(tmp_path, rng):
             if op == "put":
                 raw = pool[i]
                 cid = cid_of(raw)
-                fresh = not be.has(cid)
+                is_new = not be.has(cid)
                 be.put(raw)
-                if fresh:            # dedup acks are not logged
+                if is_new:           # dedup acks are not logged
                     model["puts"] += 1
                     model["logical_bytes"] += len(raw)
                     model["physical_bytes"] += len(raw)
@@ -766,9 +766,14 @@ def test_make_backend_specs(backend, tmp_path, rng):
 
 @pytest.mark.parametrize("backend", ["memory"], indirect=True)
 def test_fphash_many_matches_per_chunk_kernel(backend, rng):
-    from repro.kernels.fphash import fphash, fphash_many
+    """The batched kernel body (interpret mode) and the numpy sponge
+    both give the per-chunk oracle's digests."""
+    from repro.kernels.fphash import fphash_many_host, fphash_many_kernel
+    from repro.kernels.ref import fphash_ref
     blobs = [rng.bytes(n) for n in (0, 1, 300, 4096, 4097, 9000)]
-    assert fphash_many(blobs) == [fphash(b) for b in blobs]
+    want = [fphash_ref(b) for b in blobs]
+    assert fphash_many_kernel(blobs, interpret=True) == want
+    assert fphash_many_host(blobs) == want
 
 
 @pytest.mark.parametrize("backend", ["memory"], indirect=True)
