@@ -382,20 +382,21 @@ class ForkBase:
         With ``attest=True`` the epoch closes with a delta attestation
         committing to the folded heads.  Returns a live.EpochReport."""
         from ..live.table import EpochReport
-        rep = EpochReport()
-        for t in list(self._live.values()):
-            if t.dirty_count:
-                rep.folds.append(t.fold(context=context))
-        folded = rep.folded_uids
-        if folded:
-            cluster = getattr(self.store, "cluster", None)
-            fence = (cluster.gc_fence if cluster is not None
-                     else self.gc_fence)
-            fence.pin(folded)
-            self._gc_attest_fence(folded)
-        if attest:
-            rep.attestation = self.attest(context=context, secret=secret)
-        return rep
+        with obs.trace("engine.commit_epoch"):
+            rep = EpochReport()
+            for t in list(self._live.values()):
+                if t.dirty_count:
+                    rep.folds.append(t.fold(context=context))
+            folded = rep.folded_uids
+            if folded:
+                cluster = getattr(self.store, "cluster", None)
+                fence = (cluster.gc_fence if cluster is not None
+                         else self.gc_fence)
+                fence.pin(folded)
+                self._gc_attest_fence(folded)
+            if attest:
+                rep.attestation = self.attest(context=context, secret=secret)
+            return rep
 
     def _live_fold_key(self, key: bytes) -> None:
         """Fork/merge of a dirty head folds first: the archive must hold
@@ -453,11 +454,12 @@ class ForkBase:
         returns, reopening the same root resumes with bit-identical
         heads and every chunk reachable from them.  A no-op flush on a
         non-durable engine."""
-        self.store.flush()
-        if self._durable_root is not None:
-            from ..storage.durable import write_durably
-            write_durably(_heads_path(self._durable_root),
-                          self.branches.snapshot())
+        with obs.trace("engine.sync"):
+            self.store.flush()
+            if self._durable_root is not None:
+                from ..storage.durable import write_durably
+                write_durably(_heads_path(self._durable_root),
+                              self.branches.snapshot())
 
     # ---------------------------------------------------- observability
     def observe(self) -> dict:
@@ -768,14 +770,15 @@ class ForkBase:
         content-addressed, so a cached audit path can never go stale —
         a mutated value has a new root and misses."""
         from ..proof.membership import prove_member
-        h = self.get(key, branch, uid=uid)
-        if h is None:
-            raise NoSuchRef(branch)
-        req = ("pos", pos) if pos is not None else ("key", item_key)
-        return self._cached_proof(
-            h.obj, req,
-            lambda: prove_member(self._tree_of(h.obj), pos=pos,
-                                 key=item_key))
+        with obs.trace("engine.prove_member"):
+            h = self.get(key, branch, uid=uid)
+            if h is None:
+                raise NoSuchRef(branch)
+            req = ("pos", pos) if pos is not None else ("key", item_key)
+            return self._cached_proof(
+                h.obj, req,
+                lambda: prove_member(self._tree_of(h.obj), pos=pos,
+                                     key=item_key))
 
     def prove_absence(self, key: bytes, branch: str | None = None, *,
                       uid: bytes | None = None,

@@ -30,6 +30,7 @@ from . import chunk as ck
 from .chunk import Entry
 from .chunker import (ChunkParams, DEFAULT_PARAMS, boundary_bitmap,
                       cut_bytes, cut_elements, index_cuts)
+from .. import obs
 from ..errors import InvariantViolation
 from ..storage import WriteBuffer
 
@@ -163,35 +164,36 @@ class POSTree:
     def from_root(cls, store, kind: int, root_cid: bytes,
                   params: ChunkParams = DEFAULT_PARAMS) -> "POSTree":
         """Materialize the index (not the leaves) from a stored root."""
-        root_raw = store.get(root_cid)
-        raw = ck.chunk_payload(root_raw)
-        rtype = ck.chunk_type(root_raw)
-        if rtype in (ck.UINDEX, ck.SINDEX):
-            # walk down, collecting each level's entries; each level is
-            # fetched with ONE batched get_many, not a get per node
-            levels_desc = []
-            entries = (ck.decode_sindex if rtype == ck.SINDEX
-                       else ck.decode_uindex)(raw)
-            cur = entries
-            while True:
-                levels_desc.append(cur)
-                child = store.get(cur[0].cid)
-                ctype = ck.chunk_type(child)
-                if ctype not in (ck.UINDEX, ck.SINDEX):
-                    break
-                dec = ck.decode_sindex if ctype == ck.SINDEX else ck.decode_uindex
-                nxt = []
-                for raw_c in store.get_many([e.cid for e in cur]):
-                    nxt.extend(dec(ck.chunk_payload(raw_c)))
-                cur = nxt
-            root_count = sum(e.count for e in levels_desc[0])
-            root_key = levels_desc[0][-1].key
-            levels = list(reversed(levels_desc))
-            levels.append([Entry(root_cid, root_count, root_key)])
-            return cls(store, kind, levels, params)
-        # root is a single leaf
-        count, key = cls._leaf_stats(kind, raw)
-        return cls(store, kind, [[Entry(root_cid, count, key)]], params)
+        with obs.trace("postree.from_root"):
+            root_raw = store.get(root_cid)
+            raw = ck.chunk_payload(root_raw)
+            rtype = ck.chunk_type(root_raw)
+            if rtype in (ck.UINDEX, ck.SINDEX):
+                # walk down, collecting each level's entries; each level is
+                # fetched with ONE batched get_many, not a get per node
+                levels_desc = []
+                entries = (ck.decode_sindex if rtype == ck.SINDEX
+                           else ck.decode_uindex)(raw)
+                cur = entries
+                while True:
+                    levels_desc.append(cur)
+                    child = store.get(cur[0].cid)
+                    ctype = ck.chunk_type(child)
+                    if ctype not in (ck.UINDEX, ck.SINDEX):
+                        break
+                    dec = ck.decode_sindex if ctype == ck.SINDEX else ck.decode_uindex
+                    nxt = []
+                    for raw_c in store.get_many([e.cid for e in cur]):
+                        nxt.extend(dec(ck.chunk_payload(raw_c)))
+                    cur = nxt
+                root_count = sum(e.count for e in levels_desc[0])
+                root_key = levels_desc[0][-1].key
+                levels = list(reversed(levels_desc))
+                levels.append([Entry(root_cid, root_count, root_key)])
+                return cls(store, kind, levels, params)
+            # root is a single leaf
+            count, key = cls._leaf_stats(kind, raw)
+            return cls(store, kind, [[Entry(root_cid, count, key)]], params)
 
     @staticmethod
     def _leaf_stats(kind: int, payload: bytes) -> tuple[int, bytes | None]:
@@ -382,27 +384,28 @@ class POSTree:
     def _rebuild_index(self) -> None:
         """Recompute index levels from levels[0] (P' cid patterns, §4.3.3).
         Unchanged nodes hash to their old cids and dedup in the store."""
-        self.levels = [self.levels[0]]
-        self._cum = None
-        self._keycache = None
-        self._leaf_cache.clear()
-        entries = self.levels[0]
-        is_sorted = self.kind in SORTED_KINDS
-        while len(entries) > 1:
-            cuts = index_cuts([e.cid for e in entries], self.params)
-            raws, counts, keys = [], [], []
-            start = 0
-            for c in cuts:
-                group = entries[start:c]
-                raws.append(ck.encode_sindex(group) if is_sorted
-                            else ck.encode_uindex(group))
-                counts.append(sum(e.count for e in group))
-                keys.append(group[-1].key if is_sorted else None)
-                start = c
-            nxt = [Entry(cid, cnt, key) for cid, cnt, key
-                   in zip(self._put_chunks(raws), counts, keys)]
-            self.levels.append(nxt)
-            entries = nxt
+        with obs.trace("postree.rebuild_index"):
+            self.levels = [self.levels[0]]
+            self._cum = None
+            self._keycache = None
+            self._leaf_cache.clear()
+            entries = self.levels[0]
+            is_sorted = self.kind in SORTED_KINDS
+            while len(entries) > 1:
+                cuts = index_cuts([e.cid for e in entries], self.params)
+                raws, counts, keys = [], [], []
+                start = 0
+                for c in cuts:
+                    group = entries[start:c]
+                    raws.append(ck.encode_sindex(group) if is_sorted
+                                else ck.encode_uindex(group))
+                    counts.append(sum(e.count for e in group))
+                    keys.append(group[-1].key if is_sorted else None)
+                    start = c
+                nxt = [Entry(cid, cnt, key) for cid, cnt, key
+                       in zip(self._put_chunks(raws), counts, keys)]
+                self.levels.append(nxt)
+                entries = nxt
 
     def _warmup_bytes(self, j0: int) -> bytes:
         """Last window-1 bytes of the stream before leaf j0."""
@@ -427,6 +430,14 @@ class POSTree:
         if not edits:
             return
         self._open_batch(sink)
+        # the span closes after the re-chunk's locals (an O(leaves) dict
+        # among them) are freed
+        with obs.trace("postree.splice"):
+            self._splice_span_bytes(edits)
+        self._rebuild_index()
+        self._commit_batch()
+
+    def _splice_span_bytes(self, edits) -> None:
         leaves = self.levels[0]
         cum = self._cum_counts()
         total = int(cum[-1]) if len(cum) else 0
@@ -488,8 +499,6 @@ class POSTree:
                 if not self.levels[0]:
                     self.levels[0] = self._empty(self.store, ck.BLOB,
                                                  self.params).levels[0]
-            self._rebuild_index()
-            self._commit_batch()
             return
 
     def splice_elements(self, edits: list[tuple[int, int, list[bytes],
@@ -518,7 +527,10 @@ class POSTree:
             else:
                 clusters.append([e])
         for cl in reversed(clusters):
-            self._splice_span_elements(cl)
+            # as for bytes, the span closes after the cluster's locals
+            # are freed
+            with obs.trace("postree.splice"):
+                self._splice_span_elements(cl)
         self._rebuild_index()
         self._commit_batch()
         return

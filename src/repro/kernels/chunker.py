@@ -109,15 +109,16 @@ def boundary_bitmap_pallas(data: np.ndarray, window: int, q: int,
     n = data.shape[0]
     if n == 0:
         return np.zeros(0, dtype=bool)
-    nrows = max(1, -(-n // ROW_STRIDE))
-    nrows = -(-nrows // SUBLANES) * SUBLANES   # pad rows to sublane multiple
-    padded = np.zeros(nrows * ROW_STRIDE + HALO, dtype=np.uint8)
-    padded[HALO:HALO + n] = data
-    # overlapping rows: row r covers padded[r*STRIDE : r*STRIDE + ROW_LEN)
-    rows = np.lib.stride_tricks.sliding_window_view(
-        padded, ROW_LEN)[::ROW_STRIDE]
-    out = np.asarray(_run(rows, window=window, q=q, seed=seed,
-                          interpret=interpret()))
+    with obs.trace("kernel.chunker"):
+        nrows = max(1, -(-n // ROW_STRIDE))
+        nrows = -(-nrows // SUBLANES) * SUBLANES   # pad rows to sublane multiple
+        padded = np.zeros(nrows * ROW_STRIDE + HALO, dtype=np.uint8)
+        padded[HALO:HALO + n] = data
+        # overlapping rows: row r covers padded[r*STRIDE : r*STRIDE + ROW_LEN)
+        rows = np.lib.stride_tricks.sliding_window_view(
+            padded, ROW_LEN)[::ROW_STRIDE]
+        out = np.asarray(_run(rows, window=window, q=q, seed=seed,
+                              interpret=interpret()))
     obs.inc("kernel_launches", labels={"kernel": "chunker"})
     obs.inc("kernel_bytes", n, labels={"kernel": "chunker"})
     bitmap = out.reshape(-1)[:n].astype(bool)

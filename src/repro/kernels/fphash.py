@@ -161,24 +161,25 @@ def fphash_many_kernel(blobs, *, interpret: bool = False) -> list[bytes]:
     for maxnb, idx in buckets.items():
         for s in range(0, len(idx), _MAX_ROWS):
             part = idx[s:s + _MAX_ROWS]
-            n_pad = _pow2(len(part))
-            buf = np.zeros((n_pad, maxnb * _BLOCK_BYTES), dtype=np.uint8)
-            lens = np.zeros(n_pad, dtype=np.uint32)   # pad rows: empty
-            for r, i in enumerate(part):
-                buf[r, :len(blobs[i])] = np.frombuffer(blobs[i],
-                                                       dtype=np.uint8)
-                lens[r] = len(blobs[i]) & 0xFFFFFFFF
-            words = buf.view("<u4").reshape(n_pad, maxnb * _SUB, _LANES)
-            res = np.asarray(_run(lens.view(np.int32), words, init,
-                                  interpret=interpret))
-            obs.inc("kernel_launches", labels={"kernel": "fphash"})
-            obs.inc("kernel_bytes", int(lens.sum()),
-                    labels={"kernel": "fphash"})
-            # tile g, sublane w, lane l -> word w of chunk g*128 + l
-            digests = res.transpose(0, 2, 1).reshape(-1, _SUB)
-            digests = digests[:len(part)].astype("<u4")
-            for r, i in enumerate(part):
-                out[i] = digests[r].tobytes()
+            with obs.trace("kernel.fphash"):
+                n_pad = _pow2(len(part))
+                buf = np.zeros((n_pad, maxnb * _BLOCK_BYTES), dtype=np.uint8)
+                lens = np.zeros(n_pad, dtype=np.uint32)   # pad rows: empty
+                for r, i in enumerate(part):
+                    buf[r, :len(blobs[i])] = np.frombuffer(blobs[i],
+                                                           dtype=np.uint8)
+                    lens[r] = len(blobs[i]) & 0xFFFFFFFF
+                words = buf.view("<u4").reshape(n_pad, maxnb * _SUB, _LANES)
+                res = np.asarray(_run(lens.view(np.int32), words, init,
+                                      interpret=interpret))
+                obs.inc("kernel_launches", labels={"kernel": "fphash"})
+                obs.inc("kernel_bytes", int(lens.sum()),
+                        labels={"kernel": "fphash"})
+                # tile g, sublane w, lane l -> word w of chunk g*128 + l
+                digests = res.transpose(0, 2, 1).reshape(-1, _SUB)
+                digests = digests[:len(part)].astype("<u4")
+                for r, i in enumerate(part):
+                    out[i] = digests[r].tobytes()
     return out  # type: ignore[return-value]
 
 

@@ -31,7 +31,6 @@ the dirty overlay survives and reapplies on top of the new head
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 from .. import obs
@@ -53,7 +52,7 @@ class LiveStats:
     folds: int = 0                # epoch folds committed
     auto_folds: int = 0           # folds triggered by EpochPolicy
     folded_keys: int = 0          # dirty keys folded across all epochs
-    fold_seconds: float = 0.0     # wall-clock spent folding
+    fold_seconds: float = 0.0     # spent folding, by the live.fold span
     revalidations: int = 0        # archive-head reloads (external moves)
     dirty_bytes: int = 0          # current overlay payload bytes
 
@@ -262,41 +261,41 @@ class LiveTable:
         rep = FoldReport(self.key, self.branch, self._base_uid)
         if not self._dirty:
             return rep
-        t0 = time.perf_counter()
-        m = (FMap.from_tree(self._tree) if self._tree is not None
-             else FMap(params=self.db.params))
-        deleted = 0
-        for k, v in self._dirty.items():
-            if v is _DEL:
-                m.delete(k)
-                deleted += 1
-            else:
-                m.set(k, v)
-        uid = self.db.put(self.key, m, self.branch, context=context)
-        # adopt: the committed FMap's tree IS the new head's tree
-        self._tree = m.tree
-        self._base_uid = uid
-        self._stale = False          # the head move was our own put
-        for k, v in self._dirty.items():
-            if v is _DEL:
-                self._clean.pop(k, None)
-                self._absent.add(k)
-            else:
-                self._clean[k] = v   # folded keys stay hot
-                self._absent.discard(k)
-        n = len(self._dirty)
-        self._dirty.clear()
+        with obs.trace("live.fold") as sp:
+            m = (FMap.from_tree(self._tree) if self._tree is not None
+                 else FMap(params=self.db.params))
+            deleted = 0
+            for k, v in self._dirty.items():
+                if v is _DEL:
+                    m.delete(k)
+                    deleted += 1
+                else:
+                    m.set(k, v)
+            uid = self.db.put(self.key, m, self.branch, context=context)
+            # adopt: the committed FMap's tree IS the new head's tree
+            self._tree = m.tree
+            self._base_uid = uid
+            self._stale = False          # the head move was our own put
+            for k, v in self._dirty.items():
+                if v is _DEL:
+                    self._clean.pop(k, None)
+                    self._absent.add(k)
+                else:
+                    self._clean[k] = v   # folded keys stay hot
+                    self._absent.discard(k)
+            n = len(self._dirty)
+            self._dirty.clear()
+        # the fold is timed by its span alone (0 with observability off)
+        dt = sp.duration_s if sp is not None else 0.0
         st = self.stats
         st.dirty_bytes = 0
         st.folds += 1
         st.folded_keys += n
-        dt = time.perf_counter() - t0
         st.fold_seconds += dt
         rep.uid = uid
         rep.folded_keys = n
         rep.deleted_keys = deleted
         rep.seconds = dt
-        # route the self-timed fold into the shared observability layer:
         # one journal event per epoch fold plus the fold-latency histogram
         obs.emit("live.fold", key=self.key, branch=self.branch,
                  folded_keys=n, deleted_keys=deleted, uid=uid,

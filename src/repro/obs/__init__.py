@@ -22,8 +22,8 @@ from __future__ import annotations
 from .events import EVENTS, EventLog, emit
 from .export import prometheus_text, snapshot
 from .metrics import REGISTRY, Counter, Gauge, Histogram, MetricsRegistry
-from .trace import (Span, clear_recent_spans, current_span, monotonic,
-                    recent_spans, trace)
+from .trace import (Span, annotate_with, clear_recent_spans, current_span,
+                    monotonic, recent_spans, trace)
 
 __all__ = [
     "Counter",
@@ -34,6 +34,7 @@ __all__ = [
     "MetricsRegistry",
     "REGISTRY",
     "Span",
+    "annotate_with",
     "clear_recent_spans",
     "counter",
     "current_span",

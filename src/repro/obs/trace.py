@@ -10,6 +10,12 @@ question answered from one ``with`` block at the call site.
 When the registry is disabled, ``trace()`` returns a shared null
 context manager: the whole cost is one attribute check plus a kwargs
 dict, which is what keeps the disabled-mode overhead under the CI gate.
+
+``annotate_with(factory)`` mirrors every span into another tracer: each
+span also enters ``factory(name)`` and exits it on the way out.  The
+process that holds an accelerator passes ``jax.profiler.TraceAnnotation``,
+so the spans land in the profiler's trace on the clock its device
+operations use.  This module stays stdlib-only: the factory is injected.
 """
 from __future__ import annotations
 
@@ -20,7 +26,8 @@ from collections import deque
 
 from .metrics import REGISTRY
 
-__all__ = ["Span", "trace", "current_span", "recent_spans", "monotonic"]
+__all__ = ["Span", "annotate_with", "trace", "current_span", "recent_spans",
+           "monotonic"]
 
 #: Monotonic timer helper (satellite: replaces wall-clock ``time.time()``
 #: deltas — immune to clock steps, so timings can't go negative).
@@ -34,6 +41,9 @@ _current: contextvars.ContextVar[Span | None] = contextvars.ContextVar(
 _recent_roots: deque[Span] = deque(maxlen=32)
 
 MAX_CHILDREN = 128
+
+# factory(name) -> context manager entered around every span, or None
+_annotation = None
 
 
 class Span:
@@ -158,13 +168,43 @@ class _Trace:
         return False
 
 
+class _AnnotatedTrace(_Trace):
+    """A span that also enters ``factory(name)`` around its own timing."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name, attrs, hist, factory):
+        super().__init__(name, attrs, hist)
+        self._ann = factory(name)
+
+    def __enter__(self) -> Span:
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, et, ev, tb):
+        try:
+            return super().__exit__(et, ev, tb)
+        finally:
+            self._ann.__exit__(et, ev, tb)
+
+
+def annotate_with(factory) -> None:
+    """Enter ``factory(name)`` around every span opened from now on, or
+    nothing with ``None``.  A span exits the annotation it entered, even
+    if the factory changes while it is open."""
+    global _annotation
+    _annotation = factory
+
+
 def trace(name: str, _hist=None, **attrs):
     """Open a span named ``name``.  Yields the :class:`Span` (or ``None``
     when observability is disabled).  ``_hist``: optional Histogram that
     receives the span duration on exit."""
     if not REGISTRY.enabled:
         return _NULL
-    return _Trace(name, attrs, _hist)
+    if _annotation is None:
+        return _Trace(name, attrs, _hist)
+    return _AnnotatedTrace(name, attrs, _hist, _annotation)
 
 
 def current_span() -> Span | None:

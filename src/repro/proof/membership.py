@@ -27,6 +27,7 @@ import struct
 from collections import OrderedDict
 from dataclasses import dataclass
 
+from .. import obs
 from ..core import chunk as ck
 from ..core.hashing import content_hash_many, current_hash
 from ..core.postree import SORTED_KINDS, child_by_key, child_by_pos
@@ -220,37 +221,38 @@ def prove_member(tree, *, pos: int | None = None,
     ``key``.  The claimed value is the serialized element: a single byte
     for Blob, the element for List/Set, ``pack_kv(k, v)`` for Map by
     position, the mapped value for Map by key."""
-    if (pos is None) == (key is None):
-        raise ValueError("exactly one of pos/key")
-    if key is not None:
-        if tree.kind not in SORTED_KINDS:
-            raise ValueError("key proofs need a sorted kind (Set/Map)")
-        if key == b"":
-            raise ValueError("empty keys must be proven by position")
-        found, _, _, gpos = tree.find_key(key)
-        if not found:
-            raise KeyError(key)
-        nodes, leaf = tree.audit_path(key=key)
-        value = b""
-        if tree.kind == ck.MAP:
-            for k, v in ck.unpack_kv_stream(ck.chunk_payload(leaf)):
-                if k == key:
-                    value = v
-                    break
-        return MembershipProof(MEMBER_BY_KEY, tree.kind, 0, key, value,
+    with obs.trace("proof.path"):
+        if (pos is None) == (key is None):
+            raise ValueError("exactly one of pos/key")
+        if key is not None:
+            if tree.kind not in SORTED_KINDS:
+                raise ValueError("key proofs need a sorted kind (Set/Map)")
+            if key == b"":
+                raise ValueError("empty keys must be proven by position")
+            found, _, _, gpos = tree.find_key(key)
+            if not found:
+                raise KeyError(key)
+            nodes, leaf = tree.audit_path(key=key)
+            value = b""
+            if tree.kind == ck.MAP:
+                for k, v in ck.unpack_kv_stream(ck.chunk_payload(leaf)):
+                    if k == key:
+                        value = v
+                        break
+            return MembershipProof(MEMBER_BY_KEY, tree.kind, 0, key, value,
+                                   tuple(nodes), leaf)
+        if not (0 <= pos < tree.total_count):
+            raise IndexError(pos)
+        nodes, leaf = tree.audit_path(pos=pos)
+        el = tree.get_item(pos)
+        if tree.kind == ck.BLOB:
+            value = bytes([int(el)])
+        elif tree.kind == ck.MAP:
+            value = ck.pack_kv(*el)
+        else:
+            value = bytes(el)
+        return MembershipProof(MEMBER_BY_POS, tree.kind, pos, b"", value,
                                tuple(nodes), leaf)
-    if not (0 <= pos < tree.total_count):
-        raise IndexError(pos)
-    nodes, leaf = tree.audit_path(pos=pos)
-    el = tree.get_item(pos)
-    if tree.kind == ck.BLOB:
-        value = bytes([int(el)])
-    elif tree.kind == ck.MAP:
-        value = ck.pack_kv(*el)
-    else:
-        value = bytes(el)
-    return MembershipProof(MEMBER_BY_POS, tree.kind, pos, b"", value,
-                           tuple(nodes), leaf)
 
 
 def prove_absence(tree, key: bytes) -> MembershipProof:
@@ -378,12 +380,13 @@ def _as_proof(proof) -> MembershipProof:
 def verify_member(root_cid: bytes, proof) -> Claim:
     """Stateless single-proof verification: one vectorized hash batch
     over this proof's nodes.  Raises InvalidProof; returns the Claim."""
-    p = _as_proof(proof)
-    raws = list(p.nodes) + [p.leaf]
-    digests = dict(zip(map(id, raws), content_hash_many(raws)))
-    return _verify_one(root_cid, p, lambda r: digests[id(r)],
-                       lambda r: _decode_index(r, p.kind),
-                       lambda r: _leaf_items(p.kind, r))
+    with obs.trace("proof.verify_member"):
+        p = _as_proof(proof)
+        raws = list(p.nodes) + [p.leaf]
+        digests = dict(zip(map(id, raws), content_hash_many(raws)))
+        return _verify_one(root_cid, p, lambda r: digests[id(r)],
+                           lambda r: _decode_index(r, p.kind),
+                           lambda r: _leaf_items(p.kind, r))
 
 
 def verify_member_many(items, *, strict: bool = True,

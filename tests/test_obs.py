@@ -6,6 +6,7 @@ table, so per-instance histogram caches inside stores created before
 the reset would record into orphaned instruments.
 """
 import json
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -105,6 +106,130 @@ def test_trace_nesting_and_exception_closes_span():
     assert boom.error == "RuntimeError"
     assert boom.parent_id == root.span_id
     assert root.child_seconds() <= root.duration_s
+
+
+class FakeAnnotation:
+    """A stand-in for ``jax.profiler.TraceAnnotation`` that logs its
+    enters and exits."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        FakeAnnotation.log.append(("enter", self.name))
+
+    def __exit__(self, et, ev, tb):
+        FakeAnnotation.log.append(("exit", self.name,
+                                   et.__name__ if et else None))
+        return False
+
+
+@pytest.fixture
+def annotations():
+    FakeAnnotation.log = []
+    obs.annotate_with(FakeAnnotation)
+    yield FakeAnnotation.log
+    obs.annotate_with(None)
+
+
+def test_annotation_nests_as_the_spans_nest(annotations):
+    with obs.trace("outer"):
+        with obs.trace("inner") as sp:
+            assert sp.name == "inner"
+        with obs.trace("second"):
+            pass
+    assert annotations == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner", None),
+        ("enter", "second"), ("exit", "second", None),
+        ("exit", "outer", None)]
+
+
+def test_annotation_exits_on_exception(annotations):
+    with pytest.raises(RuntimeError):
+        with obs.trace("outer"):
+            with obs.trace("boom"):
+                raise RuntimeError("bang")
+    assert annotations == [
+        ("enter", "outer"), ("enter", "boom"),
+        ("exit", "boom", "RuntimeError"), ("exit", "outer", "RuntimeError")]
+    assert obs.recent_spans()[-1].children[0].error == "RuntimeError"
+
+
+def test_annotation_exits_what_it_entered_when_unset_midway(annotations):
+    with obs.trace("open"):
+        obs.annotate_with(None)
+        with obs.trace("unannotated"):
+            pass
+    assert annotations == [("enter", "open"), ("exit", "open", None)]
+
+
+def test_no_annotation_when_unset_or_disabled(annotations):
+    obs.annotate_with(None)
+    with obs.trace("plain") as sp:
+        assert sp is not None
+    obs.annotate_with(FakeAnnotation)
+    obs.disable()
+    try:
+        with obs.trace("dead") as sp:
+            assert sp is None
+    finally:
+        obs.enable()
+    assert annotations == []
+
+
+def test_program_spans_at_their_sites(tmp_path, annotations):
+    """One live fold, one sync, one proof and its verify on a small
+    durable engine with the Pallas chunker (interpret mode off the TPU)
+    open every span the benchmark reads (bench/program_spans.json) but
+    ``kernel.fphash``: off the TPU ``fphash_many`` takes the numpy
+    sponge, so the kernel's own launch wrapper is called directly.  No
+    span takes a name of the benchmark's own annotations."""
+    from repro.core import ChunkParams
+    from repro.kernels.fphash import fphash_many_kernel
+    from repro.kernels.ops import use_pallas_chunker
+    from repro.proof import verify_member
+
+    bench = Path(__file__).resolve().parent.parent / "bench"
+    wanted = set(json.loads((bench / "program_spans.json").read_text())
+                 ["spans"])
+    names = json.loads((bench / "trace_names.json").read_text())
+    theirs = set(names["host_activities"]) | {names["window"]}
+    assert not wanted & theirs
+
+    rng = np.random.default_rng(14)
+    db = ForkBase(params=ChunkParams(q=8), durable_root=str(tmp_path))
+    table = db.live(b"state")
+    keys = [rng.bytes(20) for _ in range(400)]
+    for k in keys:
+        table.put(k, rng.bytes(100))
+    db.commit_epoch(context=b"genesis")
+    use_pallas_chunker(True)
+    try:
+        FakeAnnotation.log.clear()
+        for k in keys[::100]:
+            table.put(k, rng.bytes(100))
+        rep = db.commit_epoch(context=b"block 1")
+        db.sync()
+        root = db.get(b"state").obj.data
+        proof = db.prove_member(b"state", item_key=keys[7])
+        assert db.prove_member(b"state", item_key=keys[7]) == proof
+        verify_member(root, proof)
+    finally:
+        use_pallas_chunker(False)
+    fphash_many_kernel([b"abc"], interpret=True)
+    seen = {n for kind, n, *_ in annotations if kind == "enter"}
+    assert seen == wanted
+    assert not seen & theirs
+    assert [n for kind, n, *_ in annotations
+            if kind == "enter"].count("postree.from_root") == 1  # cached
+    epoch = next(sp for sp in reversed(obs.recent_spans())
+                 if sp.name == "engine.commit_epoch")
+    fold = epoch.children[0]
+    assert fold.name == "live.fold"
+    assert rep.folds[0].seconds == fold.duration_s > 0
+    assert table.stats.fold_seconds >= fold.duration_s
 
 
 def test_store_span_closed_on_backend_exception():
