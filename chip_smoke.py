@@ -21,11 +21,11 @@ Phases (each raises on the first wrong answer; nothing is caught):
            ``prove_member`` on 100 sampled keys, each checked with
            ``proof.verify_member`` against the folded root.
 
-Two cuts keep the run inside its time limit, both forced by host code
-whose cost grows with the state: a fold on the splice path costs
-O(leaves) per updated key, and each ``prove_member`` decodes every index
-node of the tree (``POSTree.from_root``).  At 1M accounts 10,000 updates
-a block and 1,000 proofs would take the better part of an hour.
+Two cuts keep the run inside its time limit, both forced by host code:
+a fold on the splice path re-chunks a few leaves and launches both
+kernels for each locality cluster (about one cluster per updated key),
+and each ``prove_member`` decodes every index node of the tree
+(``POSTree.from_root``).
 
 The wiki and ledger operations run twice: on the device path, then with
 the host chunker (core/rolling.py) and the vectorized numpy fphash sponge.

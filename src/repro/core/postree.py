@@ -229,6 +229,7 @@ class POSTree:
     # ------------------------------------------------------------ reads
     def _cum_counts(self) -> np.ndarray:
         if self._cum is None:
+            obs.inc("postree_leaf_index_rebuilds_total")
             self._cum = np.cumsum(
                 np.fromiter((e.count for e in self.levels[0]), dtype=np.int64,
                             count=len(self.levels[0])))
@@ -306,6 +307,7 @@ class POSTree:
 
     def _leaf_keys(self) -> list[bytes]:
         if self._keycache is None:
+            obs.inc("postree_leaf_index_rebuilds_total")
             self._keycache = [e.key for e in self.levels[0]]
         return self._keycache
 
@@ -383,12 +385,10 @@ class POSTree:
     # ------------------------------------------------------------ commit
     def _rebuild_index(self) -> None:
         """Recompute index levels from levels[0] (P' cid patterns, §4.3.3).
-        Unchanged nodes hash to their old cids and dedup in the store."""
+        Unchanged nodes hash to their old cids and dedup in the store.
+        The leaves stay as they are, so do their caches."""
         with obs.trace("postree.rebuild_index"):
             self.levels = [self.levels[0]]
-            self._cum = None
-            self._keycache = None
-            self._leaf_cache.clear()
             entries = self.levels[0]
             is_sorted = self.kind in SORTED_KINDS
             while len(entries) > 1:
@@ -421,6 +421,38 @@ class POSTree:
             j -= 1
         return b"".join(reversed(parts))
 
+    @staticmethod
+    def _resync_leaf(cum: np.ndarray, old_pos: int) -> int | None:
+        """Index of the old leaf that starts at item ``old_pos``, or None
+        when ``old_pos`` is no old leaf boundary.  Leaf counts are >= 1 in
+        any non-empty tree, so ``cum`` is strictly increasing and one
+        binary search finds the only candidate."""
+        k = int(np.searchsorted(cum, old_pos))
+        return k + 1 if k < len(cum) and int(cum[k]) == old_pos else None
+
+    def _replace_leaves(self, j0: int, s1: int, new_leaves: list[Entry],
+                        delta: int) -> None:
+        """Put ``new_leaves`` in place of old leaves [j0, s1), whose items
+        they hold after an edit that changed the item count by ``delta``,
+        and keep the leaf cumulative counts and max keys current: the
+        leaves before j0 keep theirs, the ones from s1 on shift by delta."""
+        leaves = self.levels[0]
+        cum = self._cum_counts()
+        base = int(cum[j0 - 1]) if j0 > 0 else 0
+        leaves[j0:s1] = new_leaves
+        self._leaf_cache.clear()          # leaf indices from j0 on moved
+        if not leaves:
+            self.levels[0] = self._empty(self.store, self.kind,
+                                         self.params).levels[0]
+            self._cum = self._keycache = None
+            return
+        counts = np.fromiter((e.count for e in new_leaves), dtype=np.int64,
+                             count=len(new_leaves))
+        self._cum = np.concatenate([cum[:j0], base + np.cumsum(counts),
+                                    cum[s1:] + delta])
+        if self._keycache is not None:
+            self._keycache[j0:s1] = [e.key for e in new_leaves]
+
     def splice_bytes(self, edits: list[tuple[int, int, bytes]],
                      sink=None) -> None:
         """Blob: apply [(start, end, replacement)] byte splices (sorted,
@@ -430,8 +462,6 @@ class POSTree:
         if not edits:
             return
         self._open_batch(sink)
-        # the span closes after the re-chunk's locals (an O(leaves) dict
-        # among them) are freed
         with obs.trace("postree.splice"):
             self._splice_span_bytes(edits)
         self._rebuild_index()
@@ -440,7 +470,6 @@ class POSTree:
     def _splice_span_bytes(self, edits) -> None:
         leaves = self.levels[0]
         cum = self._cum_counts()
-        total = int(cum[-1]) if len(cum) else 0
         first = min(e[0] for e in edits)
         j0 = min(int(np.searchsorted(cum, first, side="right")), len(leaves) - 1)
         base = int(cum[j0 - 1]) if j0 > 0 else 0
@@ -462,7 +491,6 @@ class POSTree:
                                       np.frombuffer(rep, dtype=np.uint8),
                                       buf[le:]])
             delta = len(buf) - len(old)
-            covered_end = int(cum[jx])            # old coords
             at_stream_end = jx == len(leaves) - 1
             wb = np.frombuffer(warm, dtype=np.uint8)
             bitmap = boundary_bitmap(np.concatenate([wb, buf]), self.params)[len(wb):]
@@ -470,13 +498,15 @@ class POSTree:
             # resync: new cut -> old offset must hit an old leaf boundary
             stable_from = (last_end - base) + delta + self.params.window
             splice_at = None   # (cut_idx, old_leaf_index)
-            cumset = {int(c): i + 1 for i, c in enumerate(cum)}
             for ci, c in enumerate(cuts[:-1] if not at_stream_end else cuts):
                 if c < stable_from:
                     continue
                 old_off = c - delta + base
-                if old_off in cumset and old_off >= last_end:
-                    splice_at = (ci, cumset[old_off])
+                if old_off < last_end:
+                    continue
+                s1 = self._resync_leaf(cum, old_off)
+                if s1 is not None:
+                    splice_at = (ci, s1)
                     break
             if splice_at is None and not at_stream_end:
                 grow *= 2
@@ -490,15 +520,8 @@ class POSTree:
                 start = c
             new_leaves = [Entry(cid, cnt) for cid, cnt
                           in zip(self._put_chunks(raws), counts)]
-            tail = leaves[splice_at[1]:] if splice_at else []
-            if len(buf) == 0 and not new_leaves and not tail and j0 == 0:
-                self.levels[0] = self._empty(self.store, ck.BLOB,
-                                             self.params).levels[0]
-            else:
-                self.levels[0] = leaves[:j0] + new_leaves + tail
-                if not self.levels[0]:
-                    self.levels[0] = self._empty(self.store, ck.BLOB,
-                                                 self.params).levels[0]
+            self._replace_leaves(j0, splice_at[1] if splice_at else len(leaves),
+                                 new_leaves, delta)
             return
 
     def splice_elements(self, edits: list[tuple[int, int, list[bytes],
@@ -511,6 +534,8 @@ class POSTree:
         as independent spans in DESCENDING order (later spans never shift
         earlier indices), so a 100-key update on a 5M-row map re-chunks
         ~100 leaves, not the whole range between the first and last key.
+        The same order keeps the leaf cumulative counts before a span
+        exact for the next, earlier span, so no span rebuilds them.
         The index levels are recomputed once at the end."""
         if self.kind == ck.BLOB:
             raise InvariantViolation("splice_elements on blob tree")
@@ -527,8 +552,6 @@ class POSTree:
             else:
                 clusters.append([e])
         for cl in reversed(clusters):
-            # as for bytes, the span closes after the cluster's locals
-            # are freed
             with obs.trace("postree.splice"):
                 self._splice_span_elements(cl)
         self._rebuild_index()
@@ -582,14 +605,16 @@ class POSTree:
             stable_el = (last_end - base) + delta
             stable_byte = (int(bytecum[stable_el]) + self.params.window
                            if 0 <= stable_el <= len(lengths) else 1 << 62)
-            cumset = {int(c): i + 1 for i, c in enumerate(cum)}
             splice_at = None
             for ci, c in enumerate(cuts[:-1] if not at_stream_end else cuts):
                 if c < stable_el or int(bytecum[c]) < stable_byte:
                     continue
                 old_idx = c - delta + base
-                if old_idx in cumset and old_idx >= last_end:
-                    splice_at = (ci, cumset[old_idx])
+                if old_idx < last_end:
+                    continue
+                s1 = self._resync_leaf(cum, old_idx)
+                if s1 is not None:
+                    splice_at = (ci, s1)
                     break
             if splice_at is None and not at_stream_end:
                 grow *= 2
@@ -605,15 +630,9 @@ class POSTree:
                 start = c
             new_leaves = [Entry(cid, cnt, key) for cid, cnt, key
                           in zip(self._put_chunks(raws), counts, lkeys)]
-            tail = leaves[splice_at[1]:] if splice_at else []
-            self.levels[0] = leaves[:j0] + new_leaves + tail
-            if not self.levels[0]:
-                self.levels[0] = self._empty(self.store, self.kind,
-                                             self.params).levels[0]
-            # invalidate caches; caller rebuilds the index once at the end
-            self._cum = None
-            self._keycache = None
-            self._leaf_cache.clear()
+            # the caller rebuilds the index once, after the last span
+            self._replace_leaves(j0, splice_at[1] if splice_at else len(leaves),
+                                 new_leaves, delta)
             return
 
     # ------------------------------------------------------------ diff
