@@ -2,7 +2,8 @@
 
 A mix file holds parameters only.  ``weights`` draws each operation's
 kind at random in those proportions (YCSB's way); ``cycle`` repeats a
-fixed pattern of ``[kind, count]`` runs.  Keys are popularity ranks
+fixed pattern of ``[kind, count]`` runs, from its ``start``-th operation
+(negative: counted from the end).  Keys are popularity ranks
 drawn from a Zipf law over the configuration's key count, rank 0 the
 hottest; the driver maps a rank to its key.
 """
@@ -27,7 +28,7 @@ class Traffic:
     """An endless stream of ``(kind, rank)`` made from one seed."""
 
     def __init__(self, mix: dict, n_keys: int, theta: float,
-                 rng: np.random.Generator):
+                 rng: np.random.Generator, start: int = 0):
         self.rng = rng
         self.cdf = zipf_cdf(n_keys, theta)
         if ("weights" in mix) == ("cycle" in mix):
@@ -40,7 +41,7 @@ class Traffic:
         else:
             self.cycle = [k for k, n in mix["cycle"] for _ in range(int(n))]
             self.kinds = sorted(set(self.cycle))
-        self._pos = 0
+        self._pos = start % len(self.cycle) if self.cycle else 0
 
     def _block(self):
         ranks = np.searchsorted(self.cdf, self.rng.random(_BLOCK),

@@ -2,14 +2,16 @@
 idle time by span, and the host time of kernel launches.
 
 The program mirrors its ``obs`` spans into the profiler's trace where the
-tracing process sets ``obs.annotate_with(jax.profiler.TraceAnnotation)``
-around ``jax.profiler.start_trace`` / ``stop_trace``, so they sit on the
-host plane on the clock the device operations use.  This reads the same
-``.xplane.pb`` as ``trace.py``: the host events named in
-``bench/program_spans.json``, clipped to the window annotation, nested
-per thread line.  A program that writes no such spans gives empty
-results, never an error.  ``bench/run.py`` does not call it yet: no
-per-layer metric of ``BENCHMARK.json`` reads program spans.
+tracing process sets ``obs.annotate_with`` around
+``jax.profiler.start_trace`` / ``stop_trace``, so they sit on the host
+plane on the clock the device operations use.  ``bench/run.py`` sets
+``mirrored(jax.profiler.TraceAnnotation)``, which writes each span under
+its own name behind the mark of ``bench/program_spans.json``.  This reads
+the same ``.xplane.pb`` as ``trace.py``: every marked host event, whatever
+the program named it, clipped to the window annotation and nested per
+thread line; the benchmark's annotations and the runtime's own events
+carry no mark.  A program that writes no spans gives empty results, never
+an error.
 """
 from __future__ import annotations
 
@@ -24,6 +26,13 @@ SPANS_FILE = Path(__file__).resolve().parent.parent / "program_spans.json"
 
 def load_spans() -> dict:
     return json.loads(SPANS_FILE.read_text())
+
+
+def mirrored(factory, program_names: dict):
+    """The factory for ``obs.annotate_with``: each program span enters
+    ``factory`` under its name behind the mark."""
+    mark = program_names["mark"]
+    return lambda name: factory(mark + name)
 
 
 def pieces(a: list[tuple[int, int]], b: list[tuple[int, int]]):
@@ -62,7 +71,8 @@ def reduce_program(device: dict[str, list[tuple[str, int, int]]],
                    trace_names: dict, program_names: dict) -> dict:
     """``device``: plane name -> [(op name, start_ns, end_ns)]; ``host``:
     thread line -> [(event name, start_ns, end_ns)], the window
-    annotation among them.  Returns seconds:
+    annotation among them; a program span's name carries the mark, and
+    the results name it without.  Returns seconds:
 
     - ``spans``: per span name its ``count``, ``total_s``, ``self_s``
       (its time less the union of its direct child spans) and
@@ -79,14 +89,15 @@ def reduce_program(device: dict[str, list[tuple[str, int, int]]],
     if not windows:
         raise ValueError(f"no {trace_names['window']!r} annotation")
     w0, w1 = min(windows)
-    keep = set(program_names["spans"])
+    mark = program_names["mark"]
     prefix = program_names["kernel_prefix"]
     stats: dict[str, dict] = {}
     segments: list[list[tuple[int, int, str]]] = []   # self time, by line
     kernel_iv: dict[str, list[tuple[int, int]]] = defaultdict(list)
     for evs in host.values():
-        tree = nest([(max(s, w0), min(e, w1), n) for n, s, e in evs
-                     if n in keep and e > w0 and s < w1])
+        tree = nest([(max(s, w0), min(e, w1), n[len(mark):])
+                     for n, s, e in evs
+                     if n.startswith(mark) and e > w0 and s < w1])
         children: dict[int, list[int]] = defaultdict(list)
         for i, (_, _, _, parent) in enumerate(tree):
             if parent >= 0:
@@ -151,13 +162,12 @@ def reduce_program(device: dict[str, list[tuple[str, int, int]]],
             "kernel_device_s": {k: v / n_dev for k, v in kernel_dev.items()}}
 
 
-def read_program(path: Path, trace_names: dict, program_names: dict,
-                 chips: int) -> dict:
-    """Reduce one recorded trace: the first ``chips`` device planes, as
-    ``trace.read_trace`` takes them, and every host thread line."""
+def load_events(path: Path, trace_names: dict, chips: int, keep) -> tuple:
+    """The first ``chips`` device planes' operations, as
+    ``trace.read_trace`` takes them, and every host thread line's events
+    whose name ``keep`` accepts."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
-    keep = set(program_names["spans"]) | {trace_names["window"]}
     planes = sorted((p for p in pd.planes
                      if p.name.startswith(trace_names["device_plane"])),
                     key=lambda p: p.name)[:chips]
@@ -172,6 +182,15 @@ def read_program(path: Path, trace_names: dict, program_names: dict,
             for line in plane.lines:
                 host[f"{plane.name}/{line.name}"] = [
                     (ev.name, int(ev.start_ns), int(ev.end_ns))
-                    for ev in line.events if ev.name in keep]
-    return reduce_program(device, host, trace_names, program_names)
+                    for ev in line.events if keep(ev.name)]
+    return device, host
 
+
+def read_program(path: Path, trace_names: dict, program_names: dict,
+                 chips: int) -> dict:
+    """Reduce one recorded trace: every marked span, every host line."""
+    mark, window = program_names["mark"], trace_names["window"]
+    device, host = load_events(
+        path, trace_names, chips,
+        lambda n: n.startswith(mark) or n == window)
+    return reduce_program(device, host, trace_names, program_names)

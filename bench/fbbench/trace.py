@@ -4,7 +4,9 @@ The trace is the ``.xplane.pb`` that ``jax.profiler`` writes; it is read
 with ``jax.profiler.ProfileData``.  What names what is data, in
 ``bench/trace_names.json``: the prefix of the device planes, the lines on
 them that hold one event per device operation, the event names of each
-kernel, and the host annotation that marks the measured window.
+kernel, and the host annotation that marks the measured window.  The
+idle gaps are attributed to the host annotations the caller names: the
+benchmark's own, whatever a driver calls them.
 """
 from __future__ import annotations
 
@@ -52,16 +54,19 @@ def op_label(name: str, kernel: str | None) -> str:
 
 
 def reduce_events(device: dict[str, list[tuple[str, int, int]]],
-                  host: list[tuple[str, int, int]], names: dict) -> dict:
+                  host: list[tuple[str, int, int]], names: dict,
+                  activities) -> dict:
     """``device``: plane name -> [(op name, start_ns, end_ns)];
-    ``host``: [(annotation, start_ns, end_ns)].  Everything is clipped
-    to the window annotation.  Returns seconds."""
+    ``host``: [(annotation, start_ns, end_ns)]; ``activities``: the
+    annotations idle gaps are attributed to.  Everything is clipped to
+    the window annotation.  Returns seconds."""
     windows = [(s, e) for n, s, e in host if n == names["window"]]
     if not windows:
         raise ValueError(f"no {names['window']!r} annotation in the trace")
     w0, w1 = windows[0]
+    activities = set(activities)
     spans = sorted((s, e, n) for n, s, e in host
-                   if n in names["host_activities"] and e > w0 and s < w1)
+                   if n in activities and e > w0 and s < w1)
     starts = [s for s, _, _ in spans]
     busy, kernel_s = [], defaultdict(float)
     op_s: dict[str, float] = defaultdict(float)
@@ -100,13 +105,14 @@ def reduce_events(device: dict[str, list[tuple[str, int, int]]],
             "idle_gaps": [[n, v / n_dev] for n, v in gaps]}
 
 
-def read_trace(path: Path, names: dict, chips: int) -> dict:
-    """Reduce one recorded trace: the first ``chips`` device planes."""
+def read_trace(path: Path, names: dict, chips: int, activities) -> dict:
+    """Reduce one recorded trace: the first ``chips`` device planes, idle
+    gaps by the host annotations named in ``activities``."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
     device: dict[str, list[tuple[str, int, int]]] = {}
     host: list[tuple[str, int, int]] = []
-    keep = set(names["host_activities"]) | {names["window"]}
+    keep = set(activities) | {names["window"]}
     planes = sorted((p for p in pd.planes
                      if p.name.startswith(names["device_plane"])),
                     key=lambda p: p.name)[:chips]
@@ -122,4 +128,4 @@ def read_trace(path: Path, names: dict, chips: int) -> dict:
                     if ev.name in keep:
                         host.append((ev.name, int(ev.start_ns),
                                      int(ev.end_ns)))
-    return reduce_events(device, host, names)
+    return reduce_events(device, host, names, activities)

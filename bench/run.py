@@ -7,7 +7,10 @@ A cell names a configuration and a traffic mix.  The harness finds
 everything by name: ``bench/configs/<config>.json`` (sizes, skew,
 guarantees, flush policy, and the driver that runs it),
 ``bench/drivers/<driver>.py``, ``bench/mixes/<traffic>.json`` and one
-reader ``bench/metrics/<metric>.py`` per per-layer metric.
+reader ``bench/metrics/<metric>.py`` per per-layer metric.  A reader gets
+the window's record: the benchmark's own timers, the delta of every
+program counter, and with ``--trace 1`` the reduced device trace and the
+program's spans read from it.
 
 A run switches ForkBase to its device path (the Pallas chunker and the
 Pallas fphash cid kernel), builds the cell's state from the seed, warms
@@ -31,6 +34,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -98,11 +102,37 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
-def kernel_counters() -> dict:
+def program_counters() -> dict:
+    """Every counter in the program's registry, keyed by its name and
+    labels as the registry renders them:
+    ``kernel_launches{kernel="fphash"}``."""
     from repro import obs
-    return {f"{name}.{k}": obs.counter(name, {"kernel": k}).value
-            for name in ("kernel_launches", "kernel_bytes")
-            for k in ("chunker", "fphash")}
+    return {key: inst.value for key, inst in obs.REGISTRY.instruments()
+            if isinstance(inst, obs.Counter)}
+
+
+@contextlib.contextmanager
+def traced(jax, trace_dir: Path):
+    """Profile the block, the program's spans mirrored into the trace
+    (``fbbench.program.mirrored``); the span factory that was set before
+    is set again on every way out."""
+    from fbbench.program import load_spans, mirrored
+    from repro import obs
+    before = importlib.import_module("repro.obs.trace")._annotation
+    # host: annotations only, no Python tracer
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.enable_hlo_proto = False
+    obs.annotate_with(mirrored(jax.profiler.TraceAnnotation, load_spans()))
+    try:
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.annotate_with(before)
 
 
 def run_cell(cell: dict, args, jax, peaks: dict | None) -> dict:
@@ -128,43 +158,45 @@ def run_cell(cell: dict, args, jax, peaks: dict | None) -> dict:
         driver.warm()
         traffic = Traffic(cell["mix"], driver.n_keys, driver.theta,
                           np.random.default_rng(np.random.SeedSequence(
-                              args.seed, spawn_key=(99,))))
+                              args.seed, spawn_key=(99,))),
+                          start=cell["config"].get("cycle_start", 0))
         kinds = traffic.kinds
         driver.counted = set(cell["mix"].get("counted", kinds))
         setup_s = time.perf_counter() - T_START
-        c0, k0 = log.snapshot(), kernel_counters()
+        c0, p0 = log.snapshot(), program_counters()
         driver.begin_window()
         spans.on = True
-        if args.trace:
-            # host: the benchmark's annotations only, no Python tracer
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 1
-            opts.enable_hlo_proto = False
-            jax.profiler.start_trace(str(work / "trace"),
-                                     profiler_options=opts)
-        jax.config.update("jax_log_compiles", True)   # none expected
-        with jax.profiler.TraceAnnotation("bench.window"):
-            win = run_window(driver, traffic, args.seconds)
-        jax.config.update("jax_log_compiles", False)
-        if args.trace:
-            jax.profiler.stop_trace()
+        with (traced(jax, work / "trace") if args.trace
+              else contextlib.nullcontext()):
+            jax.config.update("jax_log_compiles", True)   # none expected
+            with jax.profiler.TraceAnnotation("bench.window"):
+                win = run_window(driver, traffic, args.seconds)
+            jax.config.update("jax_log_compiles", False)
         spans.on = False
-        c1, k1 = log.snapshot(), kernel_counters()
+        c1, p1 = log.snapshot(), program_counters()
     stats = jax.devices()[0].memory_stats() or {}
     e2e = driver.metrics(win["window_s"])
     e2e["setup_s"] = setup_s
+    counters = {k: v - p0.get(k, 0) for k, v in p1.items()}
     rec = {"window_s": win["window_s"], "ops": driver.completed,
            "attempted": win["attempted"],
            "spans": dict(spans.seconds),
-           "kernels": {k: k1[k] - k0[k] for k in k1},
-           "peaks": peaks, "trace": None}
+           "counters": counters,
+           "kernels": {f"{name}.{k}": counters.get(
+               f'{name}{{kernel="{k}"}}', 0)
+               for name in ("kernel_launches", "kernel_bytes")
+               for k in ("chunker", "fphash")},
+           "peaks": peaks, "trace": None, "program": None}
     if args.trace:
+        from fbbench.program import load_spans, read_program
         from fbbench.trace import find_xplane, load_names, read_trace
         xplane = find_xplane(work / "trace")
-        rec["trace"] = (read_trace(xplane, load_names(),
-                                   cell["workload"]["chips"])
-                        if xplane is not None else None)
+        if xplane is not None:
+            chips = cell["workload"]["chips"]
+            rec["trace"] = read_trace(xplane, load_names(), chips,
+                                      set(spans.seconds))
+            rec["program"] = read_program(xplane, load_names(),
+                                          load_spans(), chips)
     layer = {}
     for m in cell["per_layer"]:
         reader = load_module(cell["metric_files"][m["name"]],

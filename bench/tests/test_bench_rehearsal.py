@@ -95,24 +95,93 @@ def device_path_restored():
     hashing.use_sha256()
 
 
-def run_planted(cell: str, plant: str | None, seed: int) -> list:
+def run_planted(cell: str, plant: str | None, seed: int,
+                trace: int = 0) -> dict:
     import jax
     spec = run.find_cell(cell, rehearse=True)
     args = argparse.Namespace(workload=cell, seed=seed, seconds=0.5,
-                              trace=0)
+                              trace=trace)
     try:
         with planted(plant) if plant else nullcontext():
-            out = run.run_cell(spec, args, jax, None)
+            return run.run_cell(spec, args, jax, None)
     finally:
         shutil.rmtree(run.WORK / cell, ignore_errors=True)
-    return out["checks"]
 
 
 @pytest.mark.parametrize("cell,plant", [(c, p) for c in CELLS
                                         for p in [None, *PLANTS]])
 def test_plant_makes_the_run_incorrect(cell, plant, device_path_restored):
-    checks = run_planted(cell, plant, 2_900_000_007)
+    checks = run_planted(cell, plant, 2_900_000_007)["checks"]
     if plant is None:
         assert run.is_correct(checks), checks
     else:
         assert not run.is_correct(checks), checks
+
+
+@pytest.fixture
+def no_span_factory():
+    from repro import obs
+    obs.annotate_with(None)
+    yield
+    obs.annotate_with(None)
+
+
+def test_untraced_run_counts_every_program_counter_and_sets_no_factory(
+        monkeypatch, device_path_restored, no_span_factory):
+    """``--trace 0``: the program runs with no span factory, and the
+    readers get the window's delta of every counter of the program's
+    registry, the kernels' among them."""
+    from repro import obs
+    calls = []
+    monkeypatch.setattr(obs, "annotate_with", calls.append)
+    out = run_planted(CELLS[0], None, 2_900_000_011)
+    assert calls == []
+    rec = out["rec"]
+    assert rec["program"] is None and rec["trace"] is None
+    counters = rec["counters"]
+    for name in ("kernel_launches", "kernel_bytes"):
+        assert counters[f'{name}{{kernel="chunker"}}'] == \
+            rec["kernels"][f"{name}.chunker"] > 0
+    assert counters['events_total{kind="live.fold"}'] > 0
+    assert all(isinstance(v, int) and v >= 0 for v in counters.values())
+
+
+@pytest.mark.parametrize("fails", [False, True], ids=["sound", "raises"])
+def test_traced_run_restores_the_span_factory(fails, monkeypatch, tmp_path,
+                                              device_path_restored,
+                                              no_span_factory):
+    """``--trace 1``: the mirrored factory is set for the traced window
+    only, and the one set before is set again whether the window returns
+    or raises; the trace is stopped either way."""
+    import importlib
+
+    import jax
+
+    from fbbench import loop
+    from repro import obs
+    spans = importlib.import_module("repro.obs.trace")
+
+    def before(name):
+        return nullcontext()
+    in_window = []
+    window = loop.run_window
+
+    def run_window(*a, **k):
+        in_window.append(spans._annotation)
+        if fails:
+            raise RuntimeError("the window broke")
+        return window(*a, **k)
+    monkeypatch.setattr(loop, "run_window", run_window)
+    obs.annotate_with(before)
+    if fails:
+        with pytest.raises(RuntimeError, match="the window broke"):
+            run_planted(CELLS[0], None, 2_900_000_013, trace=1)
+    else:
+        rec = run_planted(CELLS[0], None, 2_900_000_013, trace=1)["rec"]
+        # the program's spans, read back from the trace by their names
+        assert rec["program"]["spans"]["engine.commit_epoch"]["count"] > 0
+        assert "postree.splice" in rec["program"]["spans"]
+    assert spans._annotation is before
+    assert len(in_window) == 1 and in_window[0] not in (None, before)
+    jax.profiler.start_trace(str(tmp_path))
+    jax.profiler.stop_trace()

@@ -16,7 +16,8 @@ NAMES = trace.load_names()
 
 
 def test_recorded_chip_trace_reduces_to_kernels_and_gaps():
-    got = trace.read_trace(DATA / "v5e_probe.xplane.pb", NAMES, chips=1)
+    got = trace.read_trace(DATA / "v5e_probe.xplane.pb", NAMES, chips=1,
+                           activities={"put"})
     assert got["devices"] == 1
     assert 0.05 < got["window_s"] < 1.0
     assert 0 < got["busy_s"] < got["window_s"]
@@ -32,7 +33,10 @@ def test_recorded_chip_trace_reduces_to_kernels_and_gaps():
         got["window_s"] - got["busy_s"], rel=1e-6)
 
 
-def test_reduction_of_hand_made_events():
+@pytest.mark.parametrize("activity", ["put", "smallbank.transfer"])
+def test_reduction_of_hand_made_events(activity):
+    """Idle gaps go to whatever annotation the caller names, and to no
+    other host event."""
     ms = 1_000_000
     device = {"/device:TPU:0": [
         ("%_run.1 = u8[8,4992]{1,0} custom-call(u8[8,5120] %rows.1)",
@@ -41,15 +45,15 @@ def test_reduction_of_hand_made_events():
          "u32[4,8,128] %words.1, u32[8,128] %init.1)", 5 * ms, 7 * ms),
         ("copy.3", 6 * ms, 8 * ms),          # overlaps the hash
         ("late", 11 * ms, 12 * ms)]}          # outside the window
-    host = [("bench.window", 0, 10 * ms), ("put", 1 * ms, 9 * ms),
+    host = [("bench.window", 0, 10 * ms), (activity, 1 * ms, 9 * ms),
             ("other", 0, 10 * ms)]
-    got = trace.reduce_events(device, host, NAMES)
+    got = trace.reduce_events(device, host, NAMES, {activity})
     assert got["window_s"] == pytest.approx(0.010)
     assert got["busy_s"] == pytest.approx(0.004)         # [2,3] + [5,8]
     assert got["kernel_s"] == pytest.approx({"chunker": 0.001,
                                              "fphash": 0.002})
     assert dict(got["idle_gaps"]) == pytest.approx(
-        {"put": 0.004, "client": 0.002})
+        {activity: 0.004, "client": 0.002})
     assert dict(got["device_ops"])["copy.3"] == pytest.approx(0.002)
 
 

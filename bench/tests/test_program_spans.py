@@ -1,18 +1,21 @@
-"""The program-span reduction (``fbbench/program.py``).
+"""The program-span reduction (``fbbench/program.py``) and the per-layer
+readers that take their numbers from it.
 
 ``data/v5e_ledger_block_spans.xplane.pb`` is a short ledger-block window
 recorded on one TPU v5 lite chip with the program's spans mirrored into
 the trace (``obs.annotate_with(jax.profiler.TraceAnnotation)`` set around
-the traced window)."""
+the traced window) under their bare names, before the mark."""
 from pathlib import Path
 
 import pytest
 
+import run
 from fbbench import program, trace
 
 DATA = Path(__file__).resolve().parent / "data"
 NAMES = trace.load_names()
 SPANS = program.load_spans()
+MARK = SPANS["mark"]
 MS = 1_000_000
 
 
@@ -21,22 +24,32 @@ def _share(got: dict, name: str) -> float:
     return 100.0 * got["spans"][name]["self_s"] / got["window_s"]
 
 
+def _reader(name):
+    return run.load_module(run.BENCH / "metrics" / f"{name}.py", name)
+
+
+def ev(name, s, e):
+    return (name, int(s * MS), int(e * MS))
+
+
 def _hand_made_program():
     """A fold and two proofs on one thread line, a span cut by the
-    window's start, a benchmark annotation (not a program span) and
-    device operations of both kernels.  Milliseconds."""
-    def ev(name, s, e):
-        return (name, int(s * MS), int(e * MS))
+    window's start, a benchmark annotation and runtime events (not
+    program spans: no mark) and device operations of both kernels.
+    Milliseconds."""
+    def pev(name, s, e):
+        return ev(MARK + name, s, e)
     host = {"python": [
         ev("bench.window", 0, 100),
-        ev("engine.sync", -5, 5),
-        ev("engine.commit_epoch", 10, 90), ev("live.fold", 11, 89),
-        ev("engine.put", 12, 88),
-        ev("postree.splice", 20, 50), ev("kernel.chunker", 25, 35),
-        ev("postree.rebuild_index", 60, 70), ev("kernel.fphash", 62, 66),
+        pev("engine.sync", -5, 5),
+        pev("engine.commit_epoch", 10, 90), pev("live.fold", 11, 89),
+        pev("engine.put", 12, 88),
+        pev("postree.splice", 20, 50), pev("kernel.chunker", 25, 35),
+        ev("PjitFunction(_run)", 26, 27),
+        pev("postree.rebuild_index", 60, 70), pev("kernel.fphash", 62, 66),
         ev("live.get", 90.5, 91),
-        ev("engine.prove_member", 92, 99), ev("postree.from_root", 93, 96),
-        ev("engine.prove_member", 99.2, 99.8)],
+        pev("engine.prove_member", 92, 99), pev("postree.from_root", 93, 96),
+        pev("engine.prove_member", 99.2, 99.8)],
         "other": [ev("python.idle", 0, 100)]}
     device = {"/device:TPU:0": [
         ev("%_run.1 = u8[8,4992]{1,0} custom-call(u8[8,5120] %rows.1)",
@@ -86,20 +99,50 @@ def test_program_reduction_of_hand_made_events():
     assert got["kernel_host_s"] * 1e3 == pytest.approx(14 - 3)
     assert {k: v * 1e3 for k, v in got["kernel_device_s"].items()} == \
         pytest.approx({"chunker": 2, "fphash": 1})
-    # the shares a later per-layer metric would read
+    # the shares the per-layer metrics read
     assert _share(got, "postree.splice") == pytest.approx(20.0)
     assert _share(got, "postree.rebuild_index") == pytest.approx(6.0)
     assert _share(got, "postree.from_root") == pytest.approx(3.0)
 
 
+def test_program_span_readers_on_hand_made_events():
+    rec = {"program": _hand_made_program(),
+           "counters": {"postree_leaf_index_rebuilds_total": 2}}
+    assert _reader("splice_share_pct").read(rec) == pytest.approx(20.0)
+    assert _reader("index_share_pct").read(rec) == pytest.approx(6.0)
+    assert _reader("tree_load_share_pct").read(rec) == pytest.approx(3.0)
+    assert _reader("kernel_host_share_pct").read(rec) == pytest.approx(11.0)
+    # two proofs, one of which loaded its tree
+    assert _reader("proof_cache_hit_pct").read(rec) == pytest.approx(50.0)
+    assert _reader("leaf_index_rebuilds_per_block").read(rec) == 2.0
+
+
+@pytest.mark.parametrize("metric", [
+    "splice_share_pct", "index_share_pct", "tree_load_share_pct",
+    "kernel_host_share_pct", "proof_cache_hit_pct",
+    "leaf_index_rebuilds_per_block"])
+def test_program_span_readers_find_nothing_to_read(metric):
+    """An untraced run, a trace with no program span, or a program with no
+    such counter: the reader returns nothing, never 0."""
+    untraced = {"program": None, "counters": {}}
+    bare = {"program": program.reduce_program(
+                {"/device:TPU:0": [("late", 5 * MS, 6 * MS)]},
+                {"python": [ev("bench.window", 0, 10)]}, NAMES, SPANS),
+            "counters": {}}
+    assert _reader(metric).read(untraced) is None
+    assert _reader(metric).read(bare) is None
+
+
 def test_program_without_spans_reduces_to_nothing():
     """A program that writes no spans into the trace (one older than its
     spans, or one traced with no factory set) gives empty results and no
-    error: the benchmark's own annotation is no program span."""
+    error: the benchmark's own annotation and an unmarked event named as a
+    program span are no program spans."""
     got = program.reduce_program(
         {"/device:TPU:0": [("late", 5 * MS, 6 * MS)]},
         {"python": [("bench.window", 0, 10 * MS),
-                    ("commit_epoch", 1 * MS, 9 * MS)]}, NAMES, SPANS)
+                    ("commit_epoch", 1 * MS, 9 * MS),
+                    ("engine.commit_epoch", 2 * MS, 8 * MS)]}, NAMES, SPANS)
     assert got["spans"] == {} and got["kernel_device_s"] == {}
     assert got["kernel_host_s"] == 0
     assert got["idle"] == pytest.approx({"none": 0.009})
@@ -112,12 +155,31 @@ def test_program_reduction_needs_the_window_annotation():
 
 
 def test_program_span_names_stay_apart_from_the_benchmarks():
-    """No program span shares a name with the benchmark's annotations, so
-    ``trace.py``'s breakdown reads the same with the spans on."""
-    ours = set(SPANS["spans"])
-    assert len(ours) == len(SPANS["spans"]) == 13
-    assert not ours & set(NAMES["host_activities"])
-    assert NAMES["window"] not in ours
+    """The mirrored factory writes each program span behind the mark, so
+    a span of any name, one a driver's annotation shares among them, is
+    told apart from the benchmark's annotations: ``trace.py``'s breakdown
+    reads the same with the spans on, and the reduction keeps every
+    marked span whatever its name."""
+    opened = []
+    factory = program.mirrored(lambda name: opened.append(name), SPANS)
+    factory("engine.put")
+    factory("ledger.transfer")
+    assert opened == [MARK + "engine.put", MARK + "ledger.transfer"]
+    assert not any(n.startswith(MARK) for n in NAMES["host_activities"])
+    assert not NAMES["window"].startswith(MARK)
+    got = program.reduce_program({}, {"python": [
+        ev("bench.window", 0, 10), ev("ledger.transfer", 1, 9),
+        ev(MARK + "ledger.transfer", 2, 8),
+        ev(MARK + "engine.put", 3, 4)]}, NAMES, SPANS)
+    assert {n: s["count"] for n, s in got["spans"].items()} == {
+        "ledger.transfer": 1, "engine.put": 1}
+    assert got["spans"]["engine.put"]["inside"] == {"ledger.transfer": 1}
+    tr = trace.reduce_events({"/device:TPU:0": []}, [
+        ("bench.window", 0, 10 * MS), ("ledger.transfer", 1 * MS, 9 * MS),
+        (MARK + "ledger.transfer", 2 * MS, 8 * MS)], NAMES,
+        {"ledger.transfer"})
+    assert dict(tr["idle_gaps"]) == pytest.approx(
+        {"ledger.transfer": 0.008, "client": 0.002})
 
 
 def test_recorded_ledger_block_trace_with_program_spans():
@@ -129,14 +191,16 @@ def test_recorded_ledger_block_trace_with_program_spans():
     reductions read it as they read the whole recording.  Its program
     opened ``postree.splice`` inside ``_splice_span_elements``, so each
     cluster's frame teardown fell to ``engine.put``; the span now closes
-    after the call, which moves time between those two, not counts."""
+    after the call, which moves time between those two, not counts.  Its
+    spans carry no mark: the documented names of ``program_spans.json``
+    are marked here as the mirrored factory marks them now."""
     path = DATA / "v5e_ledger_block_spans.xplane.pb"
-    got = program.read_program(path, NAMES, SPANS, chips=1)
-    tr = trace.read_trace(path, NAMES, chips=1)
+    got = _recorded_program(path)
+    ours = {"commit_epoch", "sync", "live.put", "live.get"}
+    tr = trace.read_trace(path, NAMES, chips=1, activities=ours)
     assert got["window_s"] == pytest.approx(tr["window_s"])
     # the benchmark's own breakdown still sees only its annotations
-    assert set(dict(tr["idle_gaps"])) == {"commit_epoch", "sync", "client",
-                                          "live.put", "live.get"}
+    assert set(dict(tr["idle_gaps"])) == ours | {"client"}
     sp = got["spans"]
     blocks = sp["engine.commit_epoch"]["count"]
     assert blocks == 3
@@ -166,3 +230,25 @@ def test_recorded_ledger_block_trace_with_program_spans():
         2.1347831757746873, rel=1e-9)
     assert 100.0 * got["kernel_host_s"] / got["window_s"] == pytest.approx(
         20.4205512017943, rel=1e-9)
+    # and what the per-layer readers make of it; the counter read 0
+    rec = {"program": got,
+           "counters": {"postree_leaf_index_rebuilds_total": 0}}
+    assert _reader("splice_share_pct").read(rec) == pytest.approx(
+        69.61477423652191, rel=1e-9)
+    assert _reader("index_share_pct").read(rec) == pytest.approx(
+        2.1347831757746873, rel=1e-9)
+    assert _reader("kernel_host_share_pct").read(rec) == pytest.approx(
+        20.4205512017943, rel=1e-9)
+    assert _reader("leaf_index_rebuilds_per_block").read(rec) == 0.0
+    # a ledger-block window has no proof
+    assert _reader("tree_load_share_pct").read(rec) is None
+    assert _reader("proof_cache_hit_pct").read(rec) is None
+
+
+def _recorded_program(path: Path) -> dict:
+    """The recorded trace reduced with its program spans marked."""
+    bare = set(SPANS["spans"])
+    device, host = program.load_events(path, NAMES, 1, lambda n: True)
+    host = {line: [(MARK + n if n in bare else n, s, e)
+                   for n, s, e in evs] for line, evs in host.items()}
+    return program.reduce_program(device, host, NAMES, SPANS)
