@@ -33,6 +33,7 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass
 
+from .. import obs
 from ..core import FBlob, FMap, ForkBase
 
 
@@ -55,6 +56,7 @@ class ForkBaseLedger:
         self._state = self.db.live(STATE_KEY) if live else None
         self._pending: list[Tx] = []
         self._writes: dict[tuple[str, str], bytes] = {}
+        self._maps: dict[str, tuple[bytes, FMap]] = {}   # key -> (uid, map)
 
     @staticmethod
     def _sk(contract: str, key: str) -> bytes:
@@ -81,45 +83,57 @@ class ForkBaseLedger:
         """Batch-commit buffered writes into a new block."""
         if self.live:
             return self._commit_live()
-        by_contract: dict[str, dict[str, bytes]] = {}
-        for (c, k), v in self._writes.items():
-            by_contract.setdefault(c, {})[k] = v
-        # 1) value blobs — one versioned Put per state key
-        l2_uids: dict[str, bytes] = {}
-        for c, kv in by_contract.items():
-            for k, v in kv.items():
-                h = self.db.get(f"{c}/{k}")
-                if h is None:
-                    uid = self.db.put(f"{c}/{k}", FBlob(v))
-                else:
-                    b = h.blob()
-                    b.replace(0, len(b), v)
-                    uid = self.db.put(f"{c}/{k}", b)
-            # 2) level-2 map for this contract (key -> blob uid)
-            h2 = self.db.get(f"__l2__/{c}")
-            m2 = h2.map() if h2 is not None else FMap()
-            for k in kv:
-                head = self.db.get(f"{c}/{k}")
-                m2.set(k.encode(), head.uid)
-            l2_uids[c] = self.db.put(f"__l2__/{c}", m2)
-        # 3) level-1 map (contract -> level-2 uid)
-        h1 = self.db.get("__l1__")
-        m1 = h1.map() if h1 is not None else FMap()
-        for c, uid in l2_uids.items():
-            m1.set(c.encode(), uid)
-        state_root = self.db.put("__l1__", m1)
-        # 4) block
-        blk = FMap({b"state": state_root,
-                    b"txs": json.dumps(
-                        [(t.contract, t.op, t.key) for t in self._pending]
-                    ).encode()})
-        block_uid = self.db.put("chain", blk,
-                                context=json.dumps(
-                                    {"height": self.height}).encode())
+        with obs.trace("ledger.commit", keys=len(self._writes)):
+            writes = list(self._writes)
+            # 1) value blobs — one versioned Put per state key, the
+            #    block's key versions committed as one put_batch (a fresh
+            #    blob of the new bytes has the root an in-place replace
+            #    would give: chunk boundaries depend on content alone)
+            uids = self.db.put_batch([
+                (f"{c}/{k}", FBlob(self._writes[c, k], params=self.db.params))
+                for c, k in writes])
+            obs.inc("ledger_key_versions_total", len(uids))
+            with obs.trace("ledger.maps"):
+                # 2) level-2 map per contract (key -> blob uid)
+                by_contract: dict[str, FMap] = {}
+                for (c, k), uid in zip(writes, uids):
+                    if c not in by_contract:
+                        by_contract[c] = self._map(f"__l2__/{c}")
+                    by_contract[c].set(k.encode(), uid)
+                l2_uids = {c: self._put_map(f"__l2__/{c}", m2)
+                           for c, m2 in by_contract.items()}
+                # 3) level-1 map (contract -> level-2 uid)
+                m1 = self._map("__l1__")
+                for c, uid in l2_uids.items():
+                    m1.set(c.encode(), uid)
+                state_root = self._put_map("__l1__", m1)
+            # 4) block
+            blk = FMap({b"state": state_root,
+                        b"txs": json.dumps(
+                            [(t.contract, t.op, t.key) for t in self._pending]
+                        ).encode()})
+            block_uid = self.db.put("chain", blk,
+                                    context=json.dumps(
+                                        {"height": self.height}).encode())
         self.height += 1
         self._pending.clear()
         self._writes.clear()
         return block_uid
+
+    def _map(self, key: str) -> FMap:
+        """The map at ``key``'s head: the handle the last commit put,
+        while no other writer has moved the head, so a block splices the
+        tree it already holds instead of reloading it."""
+        h = self.db.get(key)
+        if h is None:
+            return FMap(params=self.db.params)
+        kept = self._maps.get(key)
+        return kept[1] if kept is not None and kept[0] == h.uid else h.map()
+
+    def _put_map(self, key: str, m: FMap) -> bytes:
+        uid = self.db.put(key, m)
+        self._maps[key] = (uid, m)
+        return uid
 
     def _commit_live(self) -> bytes:
         """Live-mode commit: buffered writes land in the flat table

@@ -20,7 +20,8 @@ from .chunker import ChunkParams, DEFAULT_PARAMS
 from .chunkstore import ChunkStore
 from .. import obs
 from ..storage import StorageBackend, WriteBuffer
-from .fobject import (CHUNKABLE_TYPES, FObject, load_fobject, make_fobject)
+from .fobject import (CHUNKABLE_TYPES, FObject, load_fobject, make_fobject,
+                      make_fobjects)
 from .postree import POSTree
 from .types import (CHUNKABLE_CLASSES, FBlob, FInt, FList, FMap, FSet,
                     FString, FTuple, PRIMITIVE_CLASSES)
@@ -241,37 +242,22 @@ class ForkBase:
     def put_batch(self, requests) -> list[bytes]:
         """Coalesced multi-request put (the async runtime's dispatch
         unit): ``requests`` are ``(key, value)``, ``(key, value,
-        branch)`` or ``(key, value, branch, kwargs)`` tuples.  Plain
-        branch puts commit through ONE shared WriteBuffer — every
-        value's tree chunks and meta chunk across the whole batch hit
-        the store as a single put_many (the §4.6.1 chunk pipeline
-        lifted to the request layer) — and same-key-same-branch
-        requests chain within the batch exactly as sequential puts
-        would (the buffer's overlay serves the base version's meta
-        chunk before flush).  Head updates publish only after the
+        branch)`` or ``(key, value, branch, kwargs)`` tuples.  A run of
+        plain branch puts commits through ONE shared WriteBuffer — every
+        value's tree chunks and meta chunk across the run hit the store
+        as a single put_many (the §4.6.1 chunk pipeline lifted to the
+        request layer) — with the kernel launches of the run's new blobs
+        and meta chunks independent of its length (``_put_run``).
+        Same-key-same-branch requests chain within the run exactly as
+        sequential puts would.  Head updates publish only after the
         flush, so a reader never sees a head whose chunks are still
         buffered.  Guarded / fork-on-conflict requests (``guard_uid``,
-        ``base_uid``) need the real branch table: the batch flushes
-        around them and they take the single-put path, order
-        preserved.  Returns uids in request order."""
+        ``base_uid``) need the real branch table: the run commits
+        before them and they take the single-put path, order preserved.
+        Returns uids in request order."""
         out: list[bytes] = []
         with obs.trace("engine.put_batch", requests=len(requests)):
-            batch: WriteBuffer | None = None
-            heads: dict[tuple[bytes, str], bytes] = {}
-            pending: list[tuple[bytes, str, bytes, tuple]] = []
-
-            def _flush() -> None:
-                nonlocal batch
-                if batch is None:
-                    return
-                batch.flush()
-                for key, branch, uid, bases in pending:
-                    self.branches.on_new_version(key, uid, bases)
-                    self.branches.set_head(key, branch, uid)
-                pending.clear()
-                heads.clear()
-                batch = None
-
+            run: list[tuple[bytes, object, str, bytes]] = []
             for req in requests:
                 key, value = req[0], req[1]
                 branch = (req[2] if len(req) > 2 and req[2] is not None
@@ -279,30 +265,77 @@ class ForkBase:
                 kw = dict(req[3]) if len(req) > 3 and req[3] else {}
                 if (kw.get("base_uid") is not None
                         or kw.get("guard_uid") is not None):
-                    _flush()
+                    out += self._put_run(run)
+                    run = []
                     out.append(self._put_inner(
                         key, value, branch,
                         base_uid=kw.get("base_uid"),
                         context=kw.get("context", b""),
                         guard_uid=kw.get("guard_uid")))
                     continue
-                key = _k(key)
-                if batch is None:
-                    batch = WriteBuffer(self.store)
-                head = heads.get((key, branch))
-                if head is None:
-                    head = self.branches.head(key, branch)
-                bases = (head,) if head else ()
-                base_depth = (load_fobject(batch, head).depth
-                              if head else -1)
-                t, data = self._commit_value(value, batch)
-                obj = make_fobject(batch, t, key, data, bases,
-                                   kw.get("context", b""), base_depth)
-                heads[(key, branch)] = obj.uid
-                pending.append((key, branch, obj.uid, bases))
-                out.append(obj.uid)
-            _flush()
+                run.append((_k(key), value, branch, kw.get("context", b"")))
+            out += self._put_run(run)
         return out
+
+    def _put_run(self, run: list[tuple[bytes, object, str, bytes]]
+                 ) -> list[bytes]:
+        """Commit ``(key, value, branch, context)`` plain puts as
+        sequential puts would, in a constant number of kernel launches:
+        the new blobs (an ``FBlob`` with no tree yet) are chunked in one
+        pass and their leaves hashed in one batch
+        (``POSTree.build_bytes_many``); every other value commits as in
+        ``put``.  The meta chunks are hashed in one batch per round, a
+        round holding each (key, branch)'s n-th version of the run,
+        since a version names the one before it as its base."""
+        if not run:
+            return []
+        batch = WriteBuffer(self.store)
+        last: dict[tuple[bytes, str], int] = {}
+        prior: list[int] = []        # earlier request on the same head
+        for i, (key, _, branch, _) in enumerate(run):
+            prior.append(last.get((key, branch), -1))
+            last[(key, branch)] = i
+        heads = [self.branches.head(key, branch) if j < 0 else None
+                 for (key, _, branch, _), j in zip(run, prior)]
+        stored = list(dict.fromkeys(h for h in heads if h))
+        depth = {uid: FObject.deserialize(raw, uid).depth for uid, raw
+                 in zip(stored, batch.get_many(stored))}
+        with obs.trace("engine.commit_values", values=len(run)):
+            committed: list[tuple[int, bytes] | None] = [None] * len(run)
+            fresh: dict[ChunkParams, list[int]] = {}
+            for i, (_, value, _, _) in enumerate(run):
+                if isinstance(value, FBlob) and value.tree is None:
+                    fresh.setdefault(value.params, []).append(i)
+                else:
+                    committed[i] = self._commit_value(value, batch)
+            for params, idx in fresh.items():
+                trees = POSTree.build_bytes_many(
+                    batch, [run[i][1].read() for i in idx], params)
+                for i, tree in zip(idx, trees):
+                    committed[i] = (FBlob.TYPE, run[i][1].adopt(tree))
+            objs: list[FObject | None] = [None] * len(run)
+            rounds = [0] * len(run)
+            for i, j in enumerate(prior):
+                rounds[i] = rounds[j] + 1 if j >= 0 else 0
+            for r in range(max(rounds) + 1):
+                idx = [i for i in range(len(run)) if rounds[i] == r]
+                specs = []
+                for i in idx:
+                    key, _, _, context = run[i]
+                    j = prior[i]
+                    base = heads[i] if j < 0 else objs[j].uid
+                    base_depth = (objs[j].depth if j >= 0
+                                  else depth[base] if base else -1)
+                    t, data = committed[i]
+                    specs.append((t, key, data, (base,) if base else (),
+                                  context, base_depth))
+                for i, obj in zip(idx, make_fobjects(batch, specs)):
+                    objs[i] = obj
+        batch.flush()
+        for (key, _, branch, _), obj in zip(run, objs):
+            self.branches.on_new_version(key, obj.uid, obj.bases)
+            self.branches.set_head(key, branch, obj.uid)
+        return [obj.uid for obj in objs]
 
     def get_batch(self, requests) -> list:
         """Coalesced multi-request get: ``requests`` are ``(key,)``,
@@ -458,8 +491,9 @@ class ForkBase:
             self.store.flush()
             if self._durable_root is not None:
                 from ..storage.durable import write_durably
-                write_durably(_heads_path(self._durable_root),
-                              self.branches.snapshot())
+                with obs.trace("engine.heads_snapshot"):
+                    write_durably(_heads_path(self._durable_root),
+                                  self.branches.snapshot())
 
     # ---------------------------------------------------- observability
     def observe(self) -> dict:

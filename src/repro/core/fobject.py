@@ -7,6 +7,7 @@ chain (tamper evidence, §3.2).
 """
 from __future__ import annotations
 
+import dataclasses
 import struct
 from dataclasses import dataclass
 
@@ -85,10 +86,17 @@ def make_fobject(store, type_: int, key: bytes, data: bytes,
     ``store`` is any StorageBackend; when it is the value's WriteBuffer
     (db.put), the meta chunk rides the same put_many batch as the value's
     tree chunks, so a whole version commits in one store round-trip."""
-    obj = FObject(type_, key, data, base_depth + 1, bases, context)
-    raw = obj.serialize()
-    uid = store.put(raw)
-    return FObject(type_, key, data, base_depth + 1, bases, context, uid)
+    return make_fobjects(store, [(type_, key, data, bases, context,
+                                  base_depth)])[0]
+
+
+def make_fobjects(store, specs) -> list[FObject]:
+    """``make_fobject`` of each ``(type, key, data, bases, context,
+    base_depth)``, every meta chunk hashed and put in ONE ``put_many``."""
+    objs = [FObject(t, key, data, base_depth + 1, bases, context)
+            for t, key, data, bases, context, base_depth in specs]
+    uids = store.put_many([o.serialize() for o in objs])
+    return [dataclasses.replace(o, uid=uid) for o, uid in zip(objs, uids)]
 
 
 def load_fobject(store, uid: bytes, verify: bool = False) -> FObject:

@@ -62,6 +62,23 @@ def child_by_key(entries: list[Entry], key: bytes) -> int:
     return min(bisect.bisect_left(ks, key), len(entries) - 1)
 
 
+def _index_nodes(entries: list[Entry], kind: int, params: ChunkParams
+                 ) -> tuple[list[bytes], list[int], list[bytes | None]]:
+    """The index nodes over one level's ``entries`` (P' cid patterns,
+    §4.3.3): each node's raw chunk, item count and max key."""
+    is_sorted = kind in SORTED_KINDS
+    raws, counts, keys = [], [], []
+    start = 0
+    for c in index_cuts([e.cid for e in entries], params):
+        group = entries[start:c]
+        raws.append(ck.encode_sindex(group) if is_sorted
+                    else ck.encode_uindex(group))
+        counts.append(sum(e.count for e in group))
+        keys.append(group[-1].key if is_sorted else None)
+        start = c
+    return raws, counts, keys
+
+
 class POSTree:
     def __init__(self, store, kind: int, levels: list[list[Entry]],
                  params: ChunkParams = DEFAULT_PARAMS):
@@ -102,21 +119,60 @@ class POSTree:
     @classmethod
     def build_bytes(cls, store, data: np.ndarray | bytes,
                     params: ChunkParams = DEFAULT_PARAMS) -> "POSTree":
-        data = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(
-            data, (bytes, bytearray)) else np.asarray(data, dtype=np.uint8)
-        if data.size == 0:
-            return cls._empty(store, ck.BLOB, params)
-        cuts = cut_bytes(data, params)
-        buf = WriteBuffer(store)
-        raws, counts = [], []
+        if not isinstance(data, (bytes, bytearray)):
+            data = np.asarray(data, dtype=np.uint8).tobytes()
+        return cls.build_bytes_many(store, [data], params)[0]
+
+    @classmethod
+    def build_bytes_many(cls, store, datas: list[bytes],
+                         params: ChunkParams = DEFAULT_PARAMS
+                         ) -> list["POSTree"]:
+        """The blob tree of every value at once: ONE chunker pass over
+        the values laid end to end, ONE ``put_many`` of every value's
+        leaves and one per index level across the values, so the kernel
+        launches do not grow with the number of values.  A boundary
+        depends only on the window of bytes ending at it, so each
+        value's bitmap is its slice of the joint one with the first
+        window - 1 positions cleared, as a bitmap of its own starts:
+        roots and chunks are those of each value built alone."""
+        datas = [bytes(d) for d in datas]
+        stream = np.frombuffer(b"".join(datas), dtype=np.uint8)
+        bitmap = boundary_bitmap(stream, params) if stream.size else None
+        raws, owner, counts = [], [], []
         start = 0
-        for c in cuts:
-            raws.append(ck.encode_chunk(ck.BLOB, data[start:c].tobytes()))
-            counts.append(c - start)
-            start = c
-        leaves = [Entry(cid, cnt)
-                  for cid, cnt in zip(buf.put_many(raws), counts)]
-        return cls._from_leaves(store, ck.BLOB, leaves, params, buf=buf)
+        for i, d in enumerate(datas):
+            if not d:                         # the empty blob's one leaf
+                raws.append(ck.encode_chunk(ck.BLOB, b""))
+                owner.append(i)
+                counts.append(0)
+                continue
+            bm = bitmap[start:start + len(d)].copy()
+            bm[:params.window - 1] = False
+            lo = 0
+            for c in cut_bytes(stream[start:start + len(d)], params, bm):
+                raws.append(ck.encode_chunk(ck.BLOB, d[lo:c]))
+                owner.append(i)
+                counts.append(c - lo)
+                lo = c
+            start += len(d)
+        buf = WriteBuffer(store)
+        levels: list[list[list[Entry]]] = [[[]] for _ in datas]
+        for i, cid, cnt in zip(owner, buf.put_many(raws), counts):
+            levels[i][0].append(Entry(cid, cnt))
+        growing = [i for i, lv in enumerate(levels) if len(lv[0]) > 1]
+        while growing:
+            raws, owner, counts = [], [], []
+            for i in growing:
+                r, c, _ = _index_nodes(levels[i][-1], ck.BLOB, params)
+                raws += r
+                counts += c
+                owner += [i] * len(r)
+                levels[i].append([])
+            for i, cid, cnt in zip(owner, buf.put_many(raws), counts):
+                levels[i][-1].append(Entry(cid, cnt))
+            growing = [i for i in growing if len(levels[i][-1]) > 1]
+        buf.flush()
+        return [cls(store, ck.BLOB, lv, params) for lv in levels]
 
     @classmethod
     def build_elements(cls, store, kind: int, elements: list[bytes],
@@ -390,18 +446,9 @@ class POSTree:
         with obs.trace("postree.rebuild_index"):
             self.levels = [self.levels[0]]
             entries = self.levels[0]
-            is_sorted = self.kind in SORTED_KINDS
             while len(entries) > 1:
-                cuts = index_cuts([e.cid for e in entries], self.params)
-                raws, counts, keys = [], [], []
-                start = 0
-                for c in cuts:
-                    group = entries[start:c]
-                    raws.append(ck.encode_sindex(group) if is_sorted
-                                else ck.encode_uindex(group))
-                    counts.append(sum(e.count for e in group))
-                    keys.append(group[-1].key if is_sorted else None)
-                    start = c
+                raws, counts, keys = _index_nodes(entries, self.kind,
+                                                  self.params)
                 nxt = [Entry(cid, cnt, key) for cid, cnt, key
                        in zip(self._put_chunks(raws), counts, keys)]
                 self.levels.append(nxt)

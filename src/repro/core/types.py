@@ -74,6 +74,14 @@ class FBlob:
         self._pt = PieceTable(self._tree.total_count)
         return self._tree.root_cid
 
+    def adopt(self, tree: POSTree) -> bytes:
+        """Take ``tree``, built elsewhere from this blob's bytes (a batched
+        commit of many new blobs), as what ``commit`` would have built;
+        returns its root cid."""
+        self._tree = tree
+        self._pt = PieceTable(tree.total_count)
+        return tree.root_cid
+
     @property
     def tree(self) -> POSTree | None:
         return self._tree

@@ -153,3 +153,84 @@ def test_cluster_api_roundtrip():
     cl.put("k", b, "dev")
     assert cl.get("k", "dev").blob().read().endswith(b"!")
     assert len(cl.track("k", "dev")) == 2
+
+
+# ------------------------------------------------------ batched value commit
+
+def _blob_values(rng, n):
+    """Mixed lengths: an empty value, one of many leaves, and short ones
+    (P8: 256 B average leaf)."""
+    return ([b"", rng.bytes(6000)]
+            + [rng.bytes(int(m)) for m in rng.integers(1, 600, n - 2)])
+
+
+def _stored(db):
+    return {cid: db.store.get(cid) for cid in db.store.iter_cids()}
+
+
+@pytest.mark.parametrize("edit", ["fresh", "replace"])
+def test_put_batch_matches_sequential_puts(edit, rng):
+    """New blobs and new versions of existing keys, a key put twice in
+    one batch and a non-blob value: the same uids, heads and stored
+    chunks as one ``put`` after another.  ``replace`` gives the second
+    round's blobs as whole-value edits of the stored ones, which take
+    ``put``'s own path inside the batch."""
+    seq, bat = ForkBase(params=P8), ForkBase(params=P8)
+    keys = [f"k{i}" for i in range(40)]
+    first = _blob_values(rng, 40)
+    assert [seq.put(k, FBlob(v, params=P8)) for k, v in zip(keys, first)] \
+        == bat.put_batch([(k, FBlob(v, params=P8))
+                          for k, v in zip(keys, first)])
+    second = _blob_values(rng, 40)
+
+    def requests(db):
+        out = []
+        for k, v in zip(keys, second):
+            b = FBlob(v, params=P8)
+            if edit == "replace" and db.get(k) is not None:
+                b = db.get(k).blob()
+                b.replace(0, len(b), v)
+            out.append((k, b))
+        return out + [(keys[3], FBlob(b"again", params=P8)),
+                      (keys[3], b"a string"), (keys[1], FMap({b"a": b"1"}))]
+    assert [seq.put(k, v) for k, v in requests(seq)] \
+        == bat.put_batch(requests(bat))
+    assert seq.branches.snapshot() == bat.branches.snapshot()
+    assert _stored(seq) == _stored(bat)
+    assert bat.get(keys[1]).map().get(b"a") == b"1"
+    assert bat.get(keys[2]).blob().read() == second[2]
+
+
+def test_put_batch_launches_do_not_grow_with_the_batch(monkeypatch, rng):
+    """The chunker and ``content_hash_many`` are called as often for a
+    batch of 10 new blobs as for one of 200.  Each batch holds the same
+    empty value and the same value of many leaves; the others are
+    shorter than the rolling window, one leaf each, so the index levels
+    hashed (one batch a level) are the same in both."""
+    from repro.core import chunker, hashing
+    calls = {"chunker": 0, "hash": 0}
+    bitmap, many = chunker._bitmap_impl, hashing.content_hash_many
+
+    def counted_bitmap(*a, **k):
+        calls["chunker"] += 1
+        return bitmap(*a, **k)
+
+    def counted_many(blobs):
+        calls["hash"] += 1
+        return many(blobs)
+    monkeypatch.setattr(chunker, "_bitmap_impl", counted_bitmap)
+    monkeypatch.setattr(hashing, "content_hash_many", counted_many)
+    big = rng.bytes(6000)
+    seen = []
+    for n in (10, 200):
+        db = ForkBase(params=P8)
+        values = [b"", big] + [rng.bytes(int(m))
+                               for m in rng.integers(1, 48, n - 2)]
+        db.put_batch([(f"k{i}", FBlob(b"old", params=P8))
+                      for i in range(n)])
+        calls.update(chunker=0, hash=0)
+        db.put_batch([(f"k{i}", FBlob(v, params=P8))
+                      for i, v in enumerate(values)])
+        seen.append(dict(calls))
+    assert seen[0] == seen[1]
+    assert seen[0]["chunker"] == 1

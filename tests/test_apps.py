@@ -99,3 +99,55 @@ def test_analytics_row_col_orpheus(rng):
     rtA.update({recs[0][0]: [recs[0][0], b"777", b"0", b"x"]})
     assert rt.get(recs[0][0])[1] == b"0"
     assert rtA.get(recs[0][0])[1] == b"777"
+
+
+def test_archival_commit_matches_per_key_commit(rng):
+    """The archival ledger's batched commit gives the block uids of a
+    commit written key by key: a get, an in-place replace and a put per
+    state key, then the level-2 maps, the level-1 map and the block,
+    every blob and map chunked under the engine's parameters."""
+    import json
+
+    from repro.core import FBlob, FMap
+
+    def per_key_commit(db, height, writes, txs):
+        l2 = {}
+        for c in dict.fromkeys(c for c, _ in writes):
+            for (c2, k), v in writes.items():
+                if c2 != c:
+                    continue
+                h = db.get(f"{c}/{k}")
+                if h is None:
+                    db.put(f"{c}/{k}", FBlob(v, params=P8))
+                else:
+                    b = h.blob()
+                    b.replace(0, len(b), v)
+                    db.put(f"{c}/{k}", b)
+            h2 = db.get(f"__l2__/{c}")
+            m2 = h2.map() if h2 is not None else FMap(params=P8)
+            for c2, k in writes:
+                if c2 == c:
+                    m2.set(k.encode(), db.get(f"{c}/{k}").uid)
+            l2[c] = db.put(f"__l2__/{c}", m2)
+        h1 = db.get("__l1__")
+        m1 = h1.map() if h1 is not None else FMap(params=P8)
+        for c, uid in l2.items():
+            m1.set(c.encode(), uid)
+        root = db.put("__l1__", m1)
+        blk = FMap({b"state": root, b"txs": json.dumps(txs).encode()})
+        return db.put("chain", blk,
+                      context=json.dumps({"height": height}).encode())
+
+    fb = ForkBaseLedger(ForkBase(params=P8))
+    plain = ForkBase(params=P8)
+    for height in range(6):
+        writes, txs = {}, []
+        for _ in range(12):
+            c = ["bank", "token"][int(rng.integers(0, 2))]
+            k = f"acct{int(rng.integers(0, 30))}"
+            v = rng.bytes(int(rng.integers(0, 700)))
+            fb.write(c, k, v)
+            writes[c, k] = v
+            txs.append((c, "put", k))
+        assert fb.commit() == per_key_commit(plain, height, writes, txs)
+    assert fb.db.branches.snapshot() == plain.branches.snapshot()

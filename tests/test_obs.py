@@ -220,7 +220,8 @@ def test_program_spans_at_their_sites(tmp_path, annotations):
         use_pallas_chunker(False)
     fphash_many_kernel([b"abc"], interpret=True)
     seen = {n for kind, n, *_ in annotations if kind == "enter"}
-    assert seen == wanted
+    # the list predates the heads snapshot's own span inside sync
+    assert seen == wanted | {"engine.heads_snapshot"}
     assert not seen & theirs
     assert [n for kind, n, *_ in annotations
             if kind == "enter"].count("postree.from_root") == 1  # cached
@@ -230,6 +231,37 @@ def test_program_spans_at_their_sites(tmp_path, annotations):
     assert fold.name == "live.fold"
     assert rep.folds[0].seconds == fold.duration_s > 0
     assert table.stats.fold_seconds >= fold.duration_s
+
+
+def test_ledger_spans_and_counter_at_their_sites(tmp_path, annotations):
+    """An archival ledger block on a durable engine opens ``ledger.commit``
+    around its one ``engine.put_batch`` (the values' chunk and hash in
+    ``engine.commit_values``) and ``ledger.maps`` (the level-2 and
+    level-1 map puts); ``sync`` opens ``engine.heads_snapshot``; the
+    counter counts the block's key versions."""
+    from repro.apps import ForkBaseLedger
+    led = ForkBaseLedger(ForkBase(durable_root=str(tmp_path)))
+    for i in range(5):
+        led.write("bank", f"a{i}", b"%d" % i)
+    led.write("bank", "a1", b"7")            # one version a key a block
+    led.write("coin", "a1", b"8")
+    led.commit()
+    led.db.sync()
+    assert obs.counter("ledger_key_versions_total").value == 6
+    roots = {sp.name: sp for sp in obs.recent_spans()}
+    commit = roots["ledger.commit"]
+    assert [c.name for c in commit.children] == [
+        "engine.put_batch", "ledger.maps", "engine.put"]
+    assert [c.name for c in commit.children[0].children] == [
+        "engine.commit_values", "store.put"]     # one put_many
+    assert [c.name for c in commit.children[1].children] == [
+        "engine.put"] * 3                      # two level-2 maps, level 1
+    assert [c.name for c in roots["engine.sync"].children
+            if c.name.startswith("engine.")] == ["engine.heads_snapshot"]
+    entered = [n for kind, n, *_ in annotations if kind == "enter"]
+    for name in ("ledger.commit", "ledger.maps", "engine.commit_values",
+                 "engine.heads_snapshot"):
+        assert entered.count(name) == 1, name
 
 
 def test_store_span_closed_on_backend_exception():
